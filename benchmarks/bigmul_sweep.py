@@ -45,6 +45,7 @@ from repro.kernels import bigmul
 from repro.obs import costmodel as CM
 from repro.obs import report as RPT
 from repro.utils import jaxpr_stats as JS
+from repro.utils import compile_cache
 
 IMPLS = ("pallas_batched", "pallas_vmap", "blocked")
 
@@ -177,4 +178,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

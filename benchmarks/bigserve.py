@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.core import bigint as bi
 from repro.serving.bigint_service import BigintDivisionService
+from repro.utils import compile_cache
 
 
 def run(m_limbs=256, batch=256):
@@ -31,4 +32,5 @@ def run(m_limbs=256, batch=256):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     print(run())

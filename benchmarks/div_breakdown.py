@@ -57,6 +57,7 @@ from repro.core import shinv as S
 from repro.obs import costmodel as CM
 from repro.obs import report as RPT
 from repro.utils import jaxpr_stats as JS
+from repro.utils import compile_cache
 
 IMPLS = ("pallas_fused", "pallas_batched", "blocked")
 
@@ -320,4 +321,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

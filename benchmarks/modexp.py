@@ -52,6 +52,7 @@ from repro.kernels import ops as K
 from repro.obs import costmodel as CM
 from repro.obs import report as RPT
 from repro.utils import jaxpr_stats as JS
+from repro.utils import compile_cache
 
 _SCHEMA = 1
 
@@ -167,6 +168,7 @@ def run(sizes=(256, 512, 1024), batch=16, exp_bits=32, impl="blocked",
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--bits", default="256,512,1024")
     ap.add_argument("--batch", type=int, default=16)

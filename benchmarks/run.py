@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import sys
 
+from repro.utils import compile_cache
+
 
 def main() -> None:
     rows = []
@@ -60,4 +62,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -31,6 +31,7 @@ from repro.core import bigint as bi
 from repro.core import shinv as S
 from repro.kernels import ops as K
 from repro.obs import costmodel as CM
+from repro.utils import compile_cache
 
 BUDGET_BITS = 1 << 22          # Num Bits x Num Insts
 MAX_INSTS = 256
@@ -193,4 +194,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -12,7 +12,8 @@ multiplication:
                                         raw sub-digit block (BlockSpec)
   per-thread Q-element digit loops      (T x 2T) Toeplitz tiles on the MXU
   64-bit digits                         16-bit limbs split to 8-bit
-                                        sub-digits; int32 accumulation
+                                        sub-digits; bf16 MXU tiles with
+                                        f32 sums, int32 accumulation
   warp shuffles for carries             carry pre-resolution fused into
                                         the kernel epilogue; one short
                                         associative-scan fixup in XLA
@@ -33,7 +34,7 @@ Two generations of the kernel live here:
     runs in XLA on raw per-diagonal sums.
   * `mul_pallas_batched` -- the batch is a native leading grid axis
     (BLOCK_B instances per grid step), Toeplitz tiles are staged in
-    VMEM inside the kernel by log2(T) conditional rotates of the raw
+    VMEM inside the kernel by one strided lane rotate of the raw
     sub-digit block (no host-side blowup), and the last pair of each
     diagonal pre-resolves its tile's carries in the epilogue, so XLA
     only overlap-adds small (< 2^9) digits and finishes with a 2-pass
@@ -57,6 +58,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bigint import MASK
+from . import ops as K
 from .ops import _to_u8digits, _resolve8, _pack8, BLOCK_T
 
 _I = jnp.int32
@@ -130,16 +132,15 @@ def _mul_kernel(i_ref, j_ref, d_ref, f_ref, u_ref, t_ref, o_ref):
 
 
 def _mul_pallas_raw(u8b: jax.Array, toep: jax.Array, nu: int, nv: int,
-                    t: int, interpret: bool) -> jax.Array:
+                    t: int) -> jax.Array:
     """Grid over diagonal-sorted block pairs -> (ndiag, 2t) raw sums."""
     i_idx, j_idx, d_idx, first = _pair_schedule(nu, nv)
     ndiag = nu + nv - 1
     return _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first,
-                             ndiag, t, interpret)
+                             ndiag, t)
 
 
-def _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first, ndiag, t,
-                      interpret):
+def _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first, ndiag, t):
     """pallas_call over a static diagonal-sorted pair schedule.
 
     The schedule rides in SMEM via scalar prefetch; the BlockSpec index
@@ -160,19 +161,13 @@ def _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first, ndiag, t,
         _mul_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ndiag, 2 * t), _I),
-        interpret=interpret,
+        interpret=K.interpret_mode(),
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), jnp.asarray(d_idx),
       jnp.asarray(first), u8b, toep)
 
 
-def mul_pallas(u: jax.Array, v: jax.Array, out_width: int,
-               interpret: bool | None = None) -> jax.Array:
-    """Exact u*v mod B^out_width via the Pallas kernel (single instance).
-
-    interpret defaults to True off-TPU (CPU validation mode).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def mul_pallas(u: jax.Array, v: jax.Array, out_width: int) -> jax.Array:
+    """Exact u*v mod B^out_width via the Pallas kernel (single instance)."""
     t = BLOCK_T
     u8 = _to_u8digits(u.astype(_U))
     v8 = _to_u8digits(v.astype(_U))
@@ -183,7 +178,7 @@ def mul_pallas(u: jax.Array, v: jax.Array, out_width: int,
 
     u8b = u8.reshape(nu, t).astype(_I)
     toep = _toeplitz_host(v8, nv, t)
-    seg = _mul_pallas_raw(u8b, toep, nu, nv, t, interpret)   # (ndiag, 2t)
+    seg = _mul_pallas_raw(u8b, toep, nu, nv, t)              # (ndiag, 2t)
 
     ndiag = nu + nv - 1
     n8 = (ndiag + 1) * t
@@ -201,15 +196,13 @@ def mul_pallas(u: jax.Array, v: jax.Array, out_width: int,
 
 
 def mulmod_pallas(u: jax.Array, v: jax.Array, l_max: int,
-                  out_width: int, interpret: bool | None = None) -> jax.Array:
+                  out_width: int) -> jax.Array:
     """Close product: (u*v) mod B^l_max computed with only the low
     diagonals (the paper's MULTMOD work saving, Algorithm 2).
 
     l_max is a STATIC bound in base-2^16 limbs; only block diagonals
     that can touch sub-digits < 2*l_max are scheduled.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     t = BLOCK_T
     u8 = _to_u8digits(u.astype(_U))
     v8 = _to_u8digits(v.astype(_U))
@@ -235,7 +228,7 @@ def mulmod_pallas(u: jax.Array, v: jax.Array, l_max: int,
 
     ndiag = int(d_idx.max()) + 1 if len(d_idx) else 1
     seg = _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first,
-                            ndiag, t, interpret)
+                            ndiag, t)
 
     n8 = (ndiag + 1) * t
     raw = jnp.zeros((n8,), _I)
@@ -279,25 +272,22 @@ def pick_block_b(batch: int) -> int:
 
 
 def _toep_tile(vblk: jax.Array) -> jax.Array:
-    """(bb, t) sub-digit block -> (bb, t, 2t) Toeplitz tiles, in VMEM.
+    """(bb, t) sub-digit block -> (bb, t, 2t) bf16 Toeplitz tiles, in
+    VMEM.
 
-    tile[b, c, s] = vblk[b, s-c] when 0 <= s-c < t else 0.  Built as
-    log2(t) conditional rotates of the zero-padded block: row c needs
-    rotation by c, composed from the binary digits of the row index.
-    A rotate's wrap-around lands inside the length-t zero pad
-    (pad[(s-c) mod 2t] with s-c outside [0, t) always hits the pad),
-    so no boundary mask is needed.
+    tile[b, c, s] = vblk[b, s-c] when 0 <= s-c < t else 0: the
+    zero-padded block broadcast to t rows, row c rotated by c in one
+    strided lane rotate.  A rotate's wrap-around lands inside the
+    length-t zero pad (pad[(s-c) mod 2t] with s-c outside [0, t) always
+    hits the pad), so no boundary mask is needed.  The rotate works on
+    32-bit lanes; sub-digits < 2^8 are then exact in bf16, the MXU
+    operand type of `_tile_dot`.
     """
     bb, t = vblk.shape
     pad = jnp.concatenate([vblk, jnp.zeros_like(vblk)], axis=-1)
     mat = jnp.broadcast_to(pad[:, None, :], (bb, t, 2 * t))
-    c = jax.lax.broadcasted_iota(_I, (1, t, 1), 1)
-    k = 0
-    while (1 << k) < t:
-        rolled = jnp.roll(mat, 1 << k, axis=-1)
-        mat = jnp.where(((c >> k) & 1) == 1, rolled, mat)
-        k += 1
-    return mat
+    return pltpu.roll(mat, 0, 2, stride=1,
+                      stride_axis=1).astype(jnp.bfloat16)
 
 
 def _preresolve(e: jax.Array) -> jax.Array:
@@ -315,45 +305,56 @@ def _preresolve(e: jax.Array) -> jax.Array:
     for _ in range(4):                      # carry magnitude /2^8 per pass
         d = e & 0xFF
         c = e >> 8
-        up = jnp.where(idx >= 1, jnp.roll(c, 1, axis=-1), 0)
+        up = jnp.where(idx >= 1, pltpu.roll(c, 1, c.ndim - 1), 0)
         e = d + up
     return e
+
+
+def _tile_dot(u: jax.Array, toep: jax.Array) -> jax.Array:
+    """(bb, t) sub-digit rows times their (bb, t, 2t) Toeplitz tiles ->
+    (bb, 2t) int32 raw sums, one M = 1 row per instance on the MXU.
+
+    The TPU's MXU takes no int32 operands, so the product runs in bf16
+    with f32 accumulation, which is exact here: sub-digits are < 2^8
+    (exact in bf16's 8-bit significand) and a T-term sum stays below
+    T * 255^2 < 2^24 (exact in f32's 24-bit significand)."""
+    bb, t = u.shape
+    prod = jax.lax.dot_general(
+        u.reshape(bb, 1, t).astype(jnp.bfloat16), toep,
+        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)               # (bb, 1, 2t)
+    return prod.reshape(bb, 2 * t).astype(_I)
 
 
 def _mul_batched_kernel(i_ref, j_ref, d_ref, f_ref, l_ref,
                         u_ref, v_ref, o_ref):
     """One grid step: BLOCK_B instances of pair (i, j) on diagonal d.
 
-    u_ref: (bb, 1, t) sub-digit tiles of u block i; v_ref likewise for
-    v block j; o_ref: (bb, 1, 3t) widened diagonal-d accumulator.  The
+    u_ref: (bb, t) sub-digit tiles of u block i; v_ref likewise for v
+    block j; o_ref: (bb, 3t) widened diagonal-d accumulator.  The
     Toeplitz tiles never exist outside VMEM: they are rebuilt from
     v_ref by `_toep_tile` each step (pure VPU shuffles, overlapped with
     the MXU product of the previous step by the pipeline).
     """
     p = pl.program_id(1)
     t = u_ref.shape[-1]
-    toep = _toep_tile(v_ref[:, 0, :])                     # (bb, t, 2t)
-    prod = jax.lax.dot_general(
-        u_ref[:, 0, :], toep,
-        dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=_I)                        # (bb, 2t)
+    prod = _tile_dot(u_ref[...], _toep_tile(v_ref[...]))  # (bb, 2t)
 
     @pl.when(f_ref[p] == 1)
     def _init():
-        o_ref[:, 0, :] = jnp.zeros_like(o_ref[:, 0, :])
-        o_ref[:, 0, : 2 * t] = prod
+        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[:, : 2 * t] = prod
 
     @pl.when(f_ref[p] == 0)
     def _acc():
-        o_ref[:, 0, : 2 * t] = o_ref[:, 0, : 2 * t] + prod
+        o_ref[:, : 2 * t] = o_ref[:, : 2 * t] + prod
 
     @pl.when(l_ref[p] == 1)
     def _epilogue():
-        o_ref[:, 0, :] = _preresolve(o_ref[:, 0, :])
+        o_ref[...] = _preresolve(o_ref[...])
 
 
 def mul_pallas_batched(u: jax.Array, v: jax.Array, out_width: int,
-                       interpret: bool | None = None,
                        block_b: int | None = None) -> jax.Array:
     """Natively batched exact (u*v) mod B^out_width.
 
@@ -364,14 +365,10 @@ def mul_pallas_batched(u: jax.Array, v: jax.Array, out_width: int,
     per-diagonal carries pre-resolved in the kernel epilogue.  Pairs
     whose diagonal cannot touch sub-digits < 2*out_width are pruned
     from the schedule structurally, like `_mul_blocked`.
-
-    interpret defaults to True off-TPU (CPU validation mode).
     """
     if u.ndim != 2 or v.ndim != 2 or u.shape[0] != v.shape[0]:
         raise ValueError(f"expected (batch, W) operands with equal batch, "
                          f"got {u.shape} x {v.shape}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     batch = u.shape[0]
     t = BLOCK_T
     wo8 = 2 * out_width
@@ -394,8 +391,12 @@ def mul_pallas_batched(u: jax.Array, v: jax.Array, out_width: int,
     if bp > batch:
         u8 = jnp.pad(u8, ((0, bp - batch), (0, 0)))
         v8 = jnp.pad(v8, ((0, bp - batch), (0, 0)))
-    u8b = u8.reshape(bp, nu_k, t).astype(_I)
-    v8b = v8.reshape(bp, nv_k, t).astype(_I)
+    # (batch rows, tiles, bb, t): the blocks below squeeze the two
+    # leading axes, so each block's last two dims equal the array's
+    # whatever bb is (Mosaic's (8, 128) block rule)
+    nr = bp // bb
+    u8b = u8.reshape(nr, bb, nu_k, t).transpose(0, 2, 1, 3).astype(_I)
+    v8b = v8.reshape(nr, bb, nv_k, t).transpose(0, 2, 1, 3).astype(_I)
 
     i_idx, j_idx, d_idx, first, last = _pair_schedule_pruned(
         nu_k, nv_k, d_keep)
@@ -403,21 +404,25 @@ def mul_pallas_batched(u: jax.Array, v: jax.Array, out_width: int,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(bp // bb, len(i_idx)),
+        grid=(nr, len(i_idx)),
         in_specs=[
-            pl.BlockSpec((bb, 1, t), lambda b, p, i, j, d, f, l: (b, i[p], 0)),
-            pl.BlockSpec((bb, 1, t), lambda b, p, i, j, d, f, l: (b, j[p], 0)),
+            pl.BlockSpec((None, None, bb, t),
+                         lambda b, p, i, j, d, f, l: (b, i[p], 0, 0)),
+            pl.BlockSpec((None, None, bb, t),
+                         lambda b, p, i, j, d, f, l: (b, j[p], 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (bb, 1, 3 * t), lambda b, p, i, j, d, f, l: (b, d[p], 0)),
+            (None, None, bb, 3 * t),
+            lambda b, p, i, j, d, f, l: (b, d[p], 0, 0)),
     )
     seg = pl.pallas_call(
         _mul_batched_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bp, ndiag, 3 * t), _I),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((nr, ndiag, bb, 3 * t), _I),
+        interpret=K.interpret_mode(),
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), jnp.asarray(d_idx),
       jnp.asarray(first), jnp.asarray(last), u8b, v8b)
+    seg = seg.transpose(0, 2, 1, 3).reshape(bp, ndiag, 3 * t)
 
     # overlap-add of the pre-resolved tiles: global position g receives
     # the [0,t) lanes of tile g//t, the [t,2t) lanes of tile g//t - 1

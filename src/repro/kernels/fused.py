@@ -26,7 +26,7 @@ with the whole operand resident in VMEM; the glue arithmetic runs on
 those tiles between the MXU products.  The `core.arith` primitives are
 ported to Pallas-callable in-kernel forms below (`_k_*`): the
 associative carry/borrow scans become Kogge-Stone ladders of log2(W)
-static rolls, dynamic limb shifts become conditional-rotate ladders
+lane rotates, dynamic limb shifts become conditional-rotate ladders
 driven by the bits of the per-instance shift amount, and `prec` /
 `take_limb` / comparisons become masked reductions -- no gathers, no
 1-D iota, nothing the Mosaic lowering rejects.
@@ -68,7 +68,11 @@ tests/test_grid_fused.py.
 
 Off-TPU the kernels run in Pallas interpret mode (validation only; the
 launch-count reduction is structural and backend-independent, see
-benchmarks/div_breakdown.py).
+benchmarks/div_breakdown.py).  On the TPU every product is a bf16 MXU
+tile product with f32 accumulation (`bigmul._tile_dot`, exact for
+sub-digit operands), and the carry and shift ladders are loops over a
+traced lane rotate, which keeps the 2^18-bit kernels' compile time in
+minutes.
 """
 
 from __future__ import annotations
@@ -85,7 +89,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.bigint import MASK, DTYPE
 from repro.core import arith as A
 from . import ops as K
-from .bigmul import _toep_tile, _preresolve, pick_block_b
+from .bigmul import _tile_dot, _toep_tile, _preresolve, pick_block_b
 from .ops import BLOCK_T
 
 _I = jnp.int32
@@ -130,25 +134,29 @@ def _k_msk(u: jax.Array, width) -> jax.Array:
 def _k_scan(gen: jax.Array, prop: jax.Array) -> jax.Array:
     """Inclusive (generate, propagate) scan -> carry out of each limb.
 
-    Kogge-Stone ladder of log2(P) static rolls: the in-kernel form of
-    `arith.carry_scan`'s associative scan (identity element (0, 1))."""
+    Kogge-Stone ladder of log2(P) rolls: the in-kernel form of
+    `arith.carry_scan`'s associative scan (identity element (0, 1)).
+    The levels run as a loop with a traced rotate amount, so the
+    kernel holds one level's code, not log2(P) copies: at 2^18-bit
+    widths the unrolled ladders dominated the Mosaic compile time."""
     p_ = gen.shape[-1]
     idx = _iota(p_)
-    g, p = gen, prop
-    sft = 1
-    while sft < p_:
-        gs = jnp.where(idx >= sft, jnp.roll(g, sft, axis=-1), 0)
-        ps = jnp.where(idx >= sft, jnp.roll(p, sft, axis=-1), 1)
-        g = g | (p & gs)
-        p = p & ps
-        sft <<= 1
+
+    def level(k, gp):
+        g, p = gp
+        sft = jnp.left_shift(1, k)
+        gs = jnp.where(idx >= sft, _k_roll(g, sft), 0)
+        ps = jnp.where(idx >= sft, _k_roll(p, sft), 1)
+        return g | (p & gs), p & ps
+
+    g, _ = jax.lax.fori_loop(0, (p_ - 1).bit_length(), level, (gen, prop))
     return g
 
 
 def _k_carry_in(gen: jax.Array, prop: jax.Array) -> jax.Array:
     """Exclusive form of `_k_scan`: carry INTO each limb."""
     g = _k_scan(gen, prop)
-    return jnp.where(_iota(g.shape[-1]) >= 1, jnp.roll(g, 1, axis=-1), 0)
+    return jnp.where(_iota(g.shape[-1]) >= 1, _k_roll(g, 1), 0)
 
 
 def _k_add(u: jax.Array, v: jax.Array, width) -> jax.Array:
@@ -178,8 +186,14 @@ def _k_lt(u: jax.Array, v: jax.Array) -> jax.Array:
     return g[:, -1:] != 0
 
 
+def _k_any(mask: jax.Array) -> jax.Array:
+    """Row-wise any() as a (bb, 1) bool column, reduced in int32
+    (Mosaic cannot truncate a reduced i8 back to i1)."""
+    return jnp.max(mask.astype(_I), axis=-1, keepdims=True) != 0
+
+
 def _k_is_zero(u: jax.Array) -> jax.Array:
-    return ~jnp.any(u != 0, axis=-1, keepdims=True)
+    return ~_k_any(u != 0)
 
 
 def _k_prec(u: jax.Array) -> jax.Array:
@@ -194,26 +208,32 @@ def _k_take(u: jax.Array, i) -> jax.Array:
                    axis=-1, keepdims=True)
 
 
+def _k_roll(u: jax.Array, sft) -> jax.Array:
+    """jnp.roll(u, sft, axis=-1) for 0 <= sft < P, sft static or traced
+    (a lane rotate either way)."""
+    return pltpu.roll(u, sft, u.ndim - 1)
+
+
 def _k_shift(u: jax.Array, n, width) -> jax.Array:
     """Whole limb shift by n (arith.shift at array width `width`).
 
     Static python n: one roll.  Per-instance traced n (a (bb, 1)
-    column): a ladder of log2(P) conditional rolls driven by the bits
+    column): a loop of log2(P) conditional rolls driven by the bits
     of n mod P -- the in-kernel analogue of the host-side conditional-
     rotate Toeplitz staging.  The validity mask uses the UN-reduced n,
     so |n| >= width correctly yields zero."""
     p_ = u.shape[-1]
     idx = _iota(p_)
     if isinstance(n, int):
-        r = jnp.roll(u, n, axis=-1) if n % p_ else u
+        r = _k_roll(u, n % p_) if n % p_ else u
     else:
         nn = jnp.remainder(n.astype(_I), p_)        # floor-mod -> [0, P)
-        r = u
-        k = 0
-        while (1 << k) < p_:
-            r = jnp.where(((nn >> k) & 1) == 1,
-                          jnp.roll(r, 1 << k, axis=-1), r)
-            k += 1
+
+        def bit(k, r):
+            return jnp.where(((nn >> k) & 1) == 1,
+                             _k_roll(r, jnp.left_shift(1, k)), r)
+
+        r = jax.lax.fori_loop(0, (p_ - 1).bit_length(), bit, u)
     src = idx - n
     return jnp.where((src >= 0) & (src < width) & (idx < width), r, 0)
 
@@ -245,17 +265,64 @@ def _k_sub_pow(u: jax.Array, p, width) -> jax.Array:
 # resolution, all on the VMEM-resident tiles
 # ---------------------------------------------------------------------------
 
-def _k_split8(u: jax.Array) -> jax.Array:
-    """(bb, P) base-2^16 limbs -> (bb, 2P) base-2^8 sub-digits."""
-    lo = u & 0xFF
-    hi = (u >> 8) & 0xFF
-    return jnp.stack([lo, hi], axis=-1).reshape(u.shape[0], -1)
+def _k_interleave(lo: jax.Array, hi: jax.Array) -> jax.Array:
+    """Two (bb, T) digit rows -> (bb, 2T) with lo at even and hi at odd
+    positions.  Mosaic has no lane-interleaving reshape, so this runs
+    on the MXU against 0/1 spread matrices; it is exact because every
+    output is a single digit < 2^8."""
+    t = lo.shape[-1]
+    r = jax.lax.broadcasted_iota(_I, (t, 2 * t), 0)
+    c = jax.lax.broadcasted_iota(_I, (t, 2 * t), 1)
+    out = (jnp.dot(lo.astype(jnp.bfloat16),
+                   (c == 2 * r).astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+           + jnp.dot(hi.astype(jnp.bfloat16),
+                     (c == 2 * r + 1).astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32))
+    return out.astype(_I)
+
+
+def _k_split8(u: jax.Array, n8: int) -> jax.Array:
+    """First n8 base-2^8 sub-digits of (bb, P) base-2^16 limbs, as
+    (bb, n8).  P and n8 are multiples of BLOCK_T; sub-digits past the
+    operand's P limbs are zero."""
+    bb, p_ = u.shape
+    t = BLOCK_T
+    chunks = []
+    for k in range(-(-n8 // (2 * t))):
+        if k * t >= p_:
+            chunks.append(jnp.zeros((bb, 2 * t), _I))
+            continue
+        c = u[:, k * t:(k + 1) * t]
+        chunks.append(_k_interleave(c & 0xFF, (c >> 8) & 0xFF))
+    return jnp.concatenate(chunks, axis=-1)[:, :n8]
+
+
+def _k_deinterleave(x: jax.Array) -> jax.Array:
+    """(bb, 2T) base-2^8 digits -> (bb, T) base-2^16 limbs.  The lane
+    de-interleave runs on the MXU against 0/1 gather matrices (exact,
+    one digit per output)."""
+    t = x.shape[-1] // 2
+    r = jax.lax.broadcasted_iota(_I, (2 * t, t), 0)
+    c = jax.lax.broadcasted_iota(_I, (2 * t, t), 1)
+    x = x.astype(jnp.bfloat16)
+    lo = jnp.dot(x, (r == 2 * c).astype(jnp.bfloat16),
+                 preferred_element_type=jnp.float32)
+    hi = jnp.dot(x, (r == 2 * c + 1).astype(jnp.bfloat16),
+                 preferred_element_type=jnp.float32)
+    return lo.astype(_I) + (hi.astype(_I) << 8)
 
 
 def _k_pack8(d: jax.Array) -> jax.Array:
-    """(bb, 2P) base-2^8 digits -> (bb, P) base-2^16 limbs."""
-    pairs = d.reshape(d.shape[0], -1, 2)
-    return pairs[..., 0] + (pairs[..., 1] << 8)
+    """(bb, n) base-2^8 digits, n a multiple of BLOCK_T -> (bb,
+    ceil(n / 2T) * T) base-2^16 limbs."""
+    bb, n = d.shape
+    t = BLOCK_T
+    if n % (2 * t):
+        d = jnp.concatenate([d, jnp.zeros((bb, t), _I)], axis=-1)
+    return jnp.concatenate(
+        [_k_deinterleave(d[:, 2 * k * t:2 * (k + 1) * t])
+         for k in range(d.shape[-1] // (2 * t))], axis=-1)
 
 
 def _k_resolve8(raw: jax.Array) -> jax.Array:
@@ -267,7 +334,7 @@ def _k_resolve8(raw: jax.Array) -> jax.Array:
     for _ in range(4):                      # carry magnitude /2^8 per pass
         d = e & 0xFF
         c = e >> 8
-        e = d + jnp.where(idx >= 1, jnp.roll(c, 1, axis=-1), 0)
+        e = d + jnp.where(idx >= 1, _k_roll(c, 1), 0)
     gen = e >> 8                            # in {0, 1}
     prop = ((e & 0xFF) == 0xFF).astype(_I)
     c = _k_carry_in(gen, prop)
@@ -295,15 +362,13 @@ def _k_mul(u: jax.Array, v: jax.Array, out_width: int, pg: int,
     t = BLOCK_T
     n8o = 2 * out_width                     # sub-digit positions kept
     d_keep = -(-n8o // t)
-    u8 = _k_split8(u)
-    v8 = _k_split8(v)
-    n8k = min(u8.shape[-1], _rup(n8o, t))   # output clip: >= n8o is dead
+    n8k = min(2 * u.shape[-1], _rup(n8o, t))  # output clip: >= n8o is dead
     n8u = min(n8k, _rup(2 * (cu or pg), t))   # content clip: zeros beyond
     n8v = min(n8k, _rup(2 * (cv or pg), t))
     nu = n8u // t
     nv = n8v // t
-    u8 = u8[:, :n8u]
-    v8 = v8[:, :n8v]
+    u8 = _k_split8(u, n8u)
+    v8 = _k_split8(v, n8v)
 
     ndiag = min(nu + nv - 1, d_keep)
     n8r = (ndiag + 1) * t                   # top tile spills one block up
@@ -314,10 +379,7 @@ def _k_mul(u: jax.Array, v: jax.Array, out_width: int, pg: int,
             d = i + j
             if d >= d_keep:
                 continue
-            prod = jax.lax.dot_general(
-                u8[:, i * t:(i + 1) * t], toep,
-                dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=_I)                   # (bb, 2t)
+            prod = _tile_dot(u8[:, i * t:(i + 1) * t], toep)  # (bb, 2t)
             segs[d] = prod if segs[d] is None else segs[d] + prod
     # overlap-add of the (bb, 2t) diagonal tiles into (bb, n8r) raw
     # sums: tile d covers [d*t, d*t + 2t) -- pure concatenates, no
@@ -379,7 +441,8 @@ def _powdiff_glue(p_, vp, wq, hpd, lpd, *, win: int, pg: int):
                         jnp.where(ptop == 0, pc,
                                   _k_msk(_k_neg_mod_pow(pc, L, win), win)))
 
-    sign = jnp.where(full, sign_full, sign_close).astype(_I)
+    # select in int32: Mosaic cannot select between i1 columns
+    sign = jnp.where(full, sign_full.astype(_I), sign_close.astype(_I))
     x = jnp.where(full, x_full, x_close)
     return sign, x
 
@@ -395,7 +458,7 @@ def _update_glue(tmp, wq, w_full, sign, h, m, act, *, win: int, pg: int):
     res_neg = _k_sub(wm, sh, win)
     # floor correction: dropped limbs of tmp nonzero -> one more off
     drop = h - 2 * m
-    dropped = jnp.any((idx < drop) & (tmp != 0), axis=-1, keepdims=True)
+    dropped = _k_any((idx < drop) & (tmp != 0))
     one0 = _k_one_at(pg, 0, win)
     res_neg = jnp.where(dropped, _k_sub(res_neg, one0, win), res_neg)
     res = jnp.where(sign, res_pos, res_neg)
@@ -501,10 +564,6 @@ def _barrett_kernel(x_ref, mu_ref, v_ref, r_ref, *, h: int, full_w: int,
 # batched pallas_call plumbing + custom_vmap wrappers
 # ---------------------------------------------------------------------------
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pad2(a: jax.Array, p: int) -> jax.Array:
     """(batch, w) -> (batch, p) int32, zero-padded on the limb axis."""
     a = a.astype(_I)
@@ -518,37 +577,56 @@ def _col(a: jax.Array, batch: int) -> jax.Array:
     return jnp.reshape(a.astype(_I), (batch, 1))
 
 
-def _launch(kernel, arrays, cols, out_widths, pg: int):
-    """pallas_call a fused kernel over the batch as the leading grid
-    axis: BLOCK_B instances per step, whole (bb, pg) operands in VMEM,
-    per-instance scalars as (bb, 1) columns."""
+def _fold(arrays, cols, pg: int, bb: int):
+    """Kernel inputs: limb arrays padded to pg, per-instance scalars as
+    columns, the batch padded to a multiple of bb and folded into
+    (rows, bb, w).  The blocks squeeze the leading axis, so each
+    block's last two dims equal the array's whatever bb is (Mosaic's
+    (8, 128) block rule)."""
     batch = arrays[0].shape[0]
-    bb = pick_block_b(batch)
     bp = -(-batch // bb) * bb
     ins = [_pad2(a, pg) for a in arrays] + [_col(c, batch) for c in cols]
     if bp > batch:
         ins = [jnp.concatenate(
             [a, jnp.zeros((bp - batch,) + a.shape[1:], a.dtype)])
             for a in ins]
-    n_arr = len(arrays)
-    in_specs = (
-        [pl.BlockSpec((bb, pg), lambda b: (b, 0)) for _ in range(n_arr)] +
-        [pl.BlockSpec((bb, 1), lambda b: (b, 0)) for _ in cols])
-    out_specs = [pl.BlockSpec((bb, 1 if w == 1 else pg), lambda b: (b, 0))
-                 for w in out_widths]
-    out_shape = [jax.ShapeDtypeStruct((bp, 1 if w == 1 else pg), _I)
-                 for w in out_widths]
+    return [a.reshape(bp // bb, bb, a.shape[-1]) for a in ins]
+
+
+def _unfold(outs, batch: int, out_widths):
+    """Kernel outputs (rows, bb, w) -> per-instance results: scalar
+    columns as (batch,) int32, limb arrays trimmed to their width."""
+    outs = outs if isinstance(outs, (list, tuple)) else (outs,)
+    res = []
+    for o, w in zip(outs, out_widths):
+        o = o.reshape(-1, o.shape[-1])
+        res.append(o[:batch, 0] if w == 1 else o[:batch, :w].astype(DTYPE))
+    return res
+
+
+def _launch(kernel, arrays, cols, out_widths, pg: int):
+    """pallas_call a fused kernel over the batch as the leading grid
+    axis: BLOCK_B instances per step, whole (bb, pg) operands in VMEM,
+    per-instance scalars as (bb, 1) columns."""
+    batch = arrays[0].shape[0]
+    bb = pick_block_b(batch)
+    ins = _fold(arrays, cols, pg, bb)
+    nr = ins[0].shape[0]
+    in_specs = [pl.BlockSpec((None, bb, a.shape[-1]), lambda b: (b, 0, 0))
+                for a in ins]
+    widths = [1 if w == 1 else pg for w in out_widths]
+    out_specs = [pl.BlockSpec((None, bb, w), lambda b: (b, 0, 0))
+                 for w in widths]
+    out_shape = [jax.ShapeDtypeStruct((nr, bb, w), _I) for w in widths]
     outs = pl.pallas_call(
         kernel,
-        grid=(bp // bb,),
+        grid=(nr,),
         in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-        interpret=_interp(),
+        interpret=K.interpret_mode(),
     )(*ins)
-    outs = outs if isinstance(outs, (list, tuple)) else (outs,)
-    return [o[:batch, 0] if w == 1 else o[:batch, :w].astype(DTYPE)
-            for o, w in zip(outs, out_widths)]
+    return _unfold(outs, batch, out_widths)
 
 
 def _bcast(axis_size, in_batched, *args):
@@ -585,16 +663,20 @@ def _bcast(axis_size, in_batched, *args):
 # VMEM for the glue pass, so the batch block `bb` shrinks as precision
 # grows (`_grid_block_b`) to keep the resident set inside the budget.
 #
-# TPU-lowering caveat (mirrors the unrolled kernels' open item): the
-# dynamic `pl.ds` tile indexing on scratch and the in-kernel reshape
-# are written against Mosaic-supported patterns (leading/sublane axis
-# only, lane axis static) but have only been validated in interpret
-# mode; schedule tapes up to ~4k steps assume SMEM can hold them.
+# Mosaic layout: the scratch tiles and the accumulator keep their tile
+# index on the leading (untiled) axis, so the PAIR steps index them
+# with traced scalars and no lane/sublane reshape is needed; the v5e
+# compiler accepts every kernel here (tests/test_tpu_compile.py).
 # ---------------------------------------------------------------------------
 
 MAX_GRID_G = 16         # base tiles per super-tile axis (per-step bound)
 GRID_TARGET_SUPERS = 36  # aim for <= this many super blocks per operand
-GRID_VMEM_BUDGET = 8 << 20   # bytes; half a ~16 MiB core, rest is slack
+# bytes of the `_grid_bytes` estimate per batch block.  The v5e Mosaic
+# compiler allocated about twice the estimate (16.04 / 16.83 MB for
+# the finalization / Refine kernels against an 8.4 MB estimate at 2^18
+# bits, bb = 2), past its default 16 MiB scoped-VMEM limit, so the
+# estimate may use a quarter of that limit.
+GRID_VMEM_BUDGET = 4 << 20
 GRID_LIMB_BUFS = 12     # VMEM accounting: full-width limb arrays live
 GRID_GLUE_BUFS = 6      # ... and accumulator-width resolve temporaries
 
@@ -675,25 +757,46 @@ def _grid_block_b(batch: int, bytes_per_instance: int) -> int:
     return bb
 
 
-def _stage8(ref, u, width) -> None:
-    """Split `u` (masked to `width` limbs) into base-2^8 sub-digits and
-    store them into a (bb, nb, BLOCK_T) scratch tile ref.  Tiles beyond
+def _lanes(k, t: int):
+    """Lane offset k * t of chunk k, static or traced (then hinted as a
+    multiple of t for Mosaic's aligned dynamic slicing)."""
+    return k * t if isinstance(k, int) else pl.multiple_of(k * t, t)
+
+
+def _stage8(ref, src_ref, width) -> None:
+    """Split the limbs in `src_ref` (masked to `width`) into base-2^8
+    sub-digits and store them into a (nb, bb, BLOCK_T) scratch tile
+    ref, tile index leading so the PAIR steps index it dynamically on
+    an untiled axis.  One 128-limb chunk (two tiles) per loop step, so
+    the kernel holds one chunk's code at any precision.  Tiles beyond
     the operand content are zero; sub-digits beyond nb*BLOCK_T can only
     influence masked-out output positions (see `_super_pairs`)."""
-    bb, nb, t = ref.shape
-    d8 = _k_split8(_k_msk(u, width))
-    need = nb * t
-    if d8.shape[-1] < need:
-        d8 = jnp.concatenate(
-            [d8, jnp.zeros((bb, need - d8.shape[-1]), _I)], axis=-1)
-    else:
-        d8 = d8[:, :need]
-    ref[...] = d8.reshape(bb, nb, t)
+    nb, _, t = ref.shape
+    n_src = src_ref.shape[-1] // t
+    lane = _iota(t)
+
+    def chunk(k):
+        off = _lanes(k, t)
+        c = src_ref[:, pl.ds(off, t)]
+        c = jnp.where(lane + off < width, c, 0)
+        return _k_interleave(c & 0xFF, (c >> 8) & 0xFF)     # (bb, 2t)
+
+    def body(k, carry):
+        d = chunk(k)
+        ref[2 * k] = d[:, :t]
+        ref[2 * k + 1] = d[:, t:]
+        return carry
+
+    _zero(ref)
+    jax.lax.fori_loop(0, min(nb // 2, n_src), body, 0)
+    if nb % 2 and nb // 2 < n_src:                  # odd tail tile
+        ref[nb - 1] = chunk(nb // 2)[:, :t]
 
 
-def _grid_pair(a_ref, b_ref, acc_ref, i, j, *, g: int) -> None:
+def _grid_pair(a_ref, b_ref, acc_ref, slab_ref, i, j, *, g: int) -> None:
     """One PAIR step: the G x G base-tile MXU products of super pair
-    (i, j) into a 3-super-tile slab, carry pre-resolution, then
+    (i, j) into a 3-super-tile slab (a (3g, bb, T) scratch, looped so
+    the kernel holds one product's code), carry pre-resolution, then
     accumulation into the persistent diagonal accumulator.
 
     Slab overflow bound: a slab position receives <= 2g tile products
@@ -703,40 +806,53 @@ def _grid_pair(a_ref, b_ref, acc_ref, i, j, *, g: int) -> None:
     the final `_k_resolve8` of the GLUE pass is exact."""
     t = BLOCK_T
     s_w = g * t
-    bb = acc_ref.shape[0]
-    ua = a_ref[:, pl.ds(i * g, g), :]                    # (bb, g, t)
-    vb = b_ref[:, pl.ds(j * g, g), :]
-    slab = jnp.zeros((bb, 3 * s_w), _I)
-    for gj in range(g):
-        toep = _toep_tile(vb[:, gj, :])                  # (bb, t, 2t)
-        for gi in range(g):
-            prod = jax.lax.dot_general(
-                ua[:, gi, :], toep,
-                dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-                preferred_element_type=_I)               # (bb, 2t)
-            off = (gi + gj) * t
-            slab = slab.at[:, off:off + 2 * t].add(prod)
-    slab = _preresolve(slab)
+
+    def row(gj, carry):
+        toep = _toep_tile(b_ref[j * g + gj])             # (bb, t, 2t)
+
+        def col(gi, carry):
+            prod = _tile_dot(a_ref[i * g + gi], toep)    # (bb, 2t)
+            o = gi + gj
+            slab_ref[o] = slab_ref[o] + prod[:, :t]
+            slab_ref[o + 1] = slab_ref[o + 1] + prod[:, t:]
+            return carry
+
+        return jax.lax.fori_loop(0, g, col, carry)
+
+    _zero(slab_ref)
+    jax.lax.fori_loop(0, g, row, 0)
+    slab = _preresolve(jnp.concatenate(
+        [slab_ref[k] for k in range(3 * g)], axis=-1))
     d = i + j
-    blk = acc_ref[:, pl.ds(d, 3), :]
-    acc_ref[:, pl.ds(d, 3), :] = blk + slab.reshape(bb, 3, s_w)
+    for k in range(3):
+        acc_ref[d + k] = acc_ref[d + k] + slab[:, k * s_w:(k + 1) * s_w]
 
 
-def _grid_resolve(acc_ref, out_width: int, pg: int) -> jax.Array:
-    """Final carry resolution of the whole accumulator -> canonical
-    product limbs masked to out_width at padded width pg (the exact
-    tail of `_k_mul`)."""
-    bb = acc_ref.shape[0]
-    raw = acc_ref[...].reshape(bb, -1)
+def _grid_resolve(acc_ref, lb_ref, out_width: int) -> jax.Array:
+    """Final carry resolution of the whole (ns, bb, s_w) accumulator
+    -> canonical product limbs masked to out_width at the padded width
+    of the (bb, pg) scratch `lb_ref` (the exact tail of `_k_mul`).  The
+    resolved digits go back into the accumulator and are packed into
+    limbs one 2T-digit chunk per loop step."""
+    ns, _, s_w = acc_ref.shape
+    t = BLOCK_T
+    g = s_w // t
+    raw = jnp.concatenate([acc_ref[k] for k in range(ns)], axis=-1)
     d8 = _k_resolve8(raw)
     d8 = jnp.where(_iota(raw.shape[-1]) < 2 * out_width, d8, 0)
-    limbs = _k_pack8(d8)
-    if limbs.shape[-1] < pg:
-        limbs = jnp.concatenate(
-            [limbs, jnp.zeros((bb, pg - limbs.shape[-1]), _I)], axis=-1)
-    else:
-        limbs = limbs[:, :pg]
-    return _k_msk(limbs, out_width)
+    for k in range(ns):
+        acc_ref[k] = d8[:, k * s_w:(k + 1) * s_w]
+
+    def chunk(c, carry):
+        halves = [acc_ref[q // g, :, pl.ds(_lanes(q % g, t), t)]
+                  for q in (2 * c, 2 * c + 1)]
+        lb_ref[:, pl.ds(_lanes(c, t), t)] = _k_deinterleave(
+            jnp.concatenate(halves, axis=-1))
+        return carry
+
+    _zero(lb_ref)
+    jax.lax.fori_loop(0, min(ns * g // 2, lb_ref.shape[-1] // t), chunk, 0)
+    return _k_msk(lb_ref[...], out_width)
 
 
 def _zero(ref) -> None:
@@ -748,7 +864,7 @@ def _zero(ref) -> None:
 def _powdiff_grid_kernel(ph_ref, i_ref, j_ref,
                          v_ref, w_ref, h_ref, l_ref, s_ref,
                          sign_ref, x_ref,
-                         a8_ref, b8_ref, acc_ref,
+                         a8_ref, b8_ref, acc_ref, slab_ref, lb_ref,
                          *, win: int, full_w: int, pg: int, g: int):
     """Grid-scheduled launch 1 of a Refine iteration."""
     p = pl.program_id(1)
@@ -756,22 +872,23 @@ def _powdiff_grid_kernel(ph_ref, i_ref, j_ref,
 
     @pl.when(ph == PH_STAGE)
     def _():
-        vp = _powdiff_prologue(v_ref[...], s_ref[...], win=win,
-                               full_w=full_w)
-        _stage8(a8_ref, vp, win)
-        _stage8(b8_ref, _k_msk(w_ref[...], win), win)
+        lb_ref[...] = _powdiff_prologue(v_ref[...], s_ref[...], win=win,
+                                        full_w=full_w)
+        _stage8(a8_ref, lb_ref, win)
+        _stage8(b8_ref, w_ref, win)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR1)
     def _():
-        _grid_pair(a8_ref, b8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(a8_ref, b8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE1)
     def _():
         vp = _powdiff_prologue(v_ref[...], s_ref[...], win=win,
                                full_w=full_w)
         wq = _k_msk(w_ref[...], win)
-        p_ = _grid_resolve(acc_ref, 2 * win, pg)
+        p_ = _grid_resolve(acc_ref, lb_ref, 2 * win)
         sign, x = _powdiff_glue(p_, vp, wq, h_ref[...], l_ref[...],
                                 win=win, pg=pg)
         sign_ref[...] = sign
@@ -781,7 +898,7 @@ def _powdiff_grid_kernel(ph_ref, i_ref, j_ref,
 def _update_grid_kernel(ph_ref, i_ref, j_ref,
                         w_ref, x_ref, sg_ref, h_ref, m_ref, a_ref,
                         o_ref,
-                        a8_ref, b8_ref, acc_ref,
+                        a8_ref, b8_ref, acc_ref, slab_ref, lb_ref,
                         *, win: int, full_w: int, pg: int, g: int):
     """Grid-scheduled launch 2 of a Refine iteration."""
     p = pl.program_id(1)
@@ -789,19 +906,20 @@ def _update_grid_kernel(ph_ref, i_ref, j_ref,
 
     @pl.when(ph == PH_STAGE)
     def _():
-        _stage8(a8_ref, _k_msk(w_ref[...], win), win)
-        _stage8(b8_ref, _k_msk(x_ref[...], win), win)
+        _stage8(a8_ref, w_ref, win)
+        _stage8(b8_ref, x_ref, win)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR1)
     def _():
-        _grid_pair(a8_ref, b8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(a8_ref, b8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE1)
     def _():
         w_full = _k_msk(w_ref[...], full_w)
         wq = _k_msk(w_full, win)
-        tmp = _grid_resolve(acc_ref, 2 * win, pg)
+        tmp = _grid_resolve(acc_ref, lb_ref, 2 * win)
         o_ref[...] = _update_glue(tmp, wq, w_full, sg_ref[...] != 0,
                                   h_ref[...], m_ref[...], a_ref[...] != 0,
                                   win=win, pg=pg)
@@ -811,6 +929,7 @@ def _correct_grid_kernel(ph_ref, i_ref, j_ref,
                          u_ref, v_ref, si_ref, h_ref,
                          q_ref, r_ref,
                          a8_ref, b8_ref, c8_ref, q8_ref, qs_ref, acc_ref,
+                         slab_ref, lb_ref,
                          *, full_w: int, pg: int, g: int):
     """Grid-scheduled divmod finalization: product u*si, quotient glue,
     product v*q, compare-and-correct -- two pair phases, the second's
@@ -820,32 +939,33 @@ def _correct_grid_kernel(ph_ref, i_ref, j_ref,
 
     @pl.when(ph == PH_STAGE)
     def _():
-        _stage8(a8_ref, _k_msk(u_ref[...], full_w), full_w)
-        _stage8(b8_ref, _k_msk(si_ref[...], full_w), full_w)
-        _stage8(c8_ref, _k_msk(v_ref[...], full_w), full_w)
+        _stage8(a8_ref, u_ref, full_w)
+        _stage8(b8_ref, si_ref, full_w)
+        _stage8(c8_ref, v_ref, full_w)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR1)
     def _():
-        _grid_pair(a8_ref, b8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(a8_ref, b8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE1)
     def _():
-        p_ = _grid_resolve(acc_ref, 2 * full_w, pg)
-        q = _quotient_glue(p_, h_ref[...], full_w=full_w)
-        qs_ref[...] = q
-        _stage8(q8_ref, q, full_w)
+        p_ = _grid_resolve(acc_ref, lb_ref, 2 * full_w)
+        qs_ref[...] = _quotient_glue(p_, h_ref[...], full_w=full_w)
+        _stage8(q8_ref, qs_ref, full_w)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR2)
     def _():
-        _grid_pair(c8_ref, q8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(c8_ref, q8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE2)
     def _():
         u = _k_msk(u_ref[...], full_w)
         v = _k_msk(v_ref[...], full_w)
-        mm = _grid_resolve(acc_ref, full_w, pg)
+        mm = _grid_resolve(acc_ref, lb_ref, full_w)
         q, r = _correct_glue(u, v, qs_ref[...], mm, full_w=full_w, pg=pg)
         q_ref[...] = q
         r_ref[...] = r
@@ -855,6 +975,7 @@ def _barrett_grid_kernel(ph_ref, i_ref, j_ref,
                          x_ref, mu_ref, v_ref,
                          r_ref,
                          a8_ref, b8_ref, c8_ref, q8_ref, qs_ref, acc_ref,
+                         slab_ref, lb_ref,
                          *, h: int, full_w: int, pg: int, g: int):
     """Grid-scheduled Barrett reduction (static shift h)."""
     p = pl.program_id(1)
@@ -862,32 +983,33 @@ def _barrett_grid_kernel(ph_ref, i_ref, j_ref,
 
     @pl.when(ph == PH_STAGE)
     def _():
-        _stage8(a8_ref, _k_msk(x_ref[...], full_w), full_w)
-        _stage8(b8_ref, _k_msk(mu_ref[...], full_w), full_w)
-        _stage8(c8_ref, _k_msk(v_ref[...], full_w), full_w)
+        _stage8(a8_ref, x_ref, full_w)
+        _stage8(b8_ref, mu_ref, full_w)
+        _stage8(c8_ref, v_ref, full_w)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR1)
     def _():
-        _grid_pair(a8_ref, b8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(a8_ref, b8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE1)
     def _():
-        p_ = _grid_resolve(acc_ref, 2 * full_w, pg)
-        q = _quotient_glue(p_, h, full_w=full_w)
-        qs_ref[...] = q
-        _stage8(q8_ref, q, full_w)
+        p_ = _grid_resolve(acc_ref, lb_ref, 2 * full_w)
+        qs_ref[...] = _quotient_glue(p_, h, full_w=full_w)
+        _stage8(q8_ref, qs_ref, full_w)
         _zero(acc_ref)
 
     @pl.when(ph == PH_PAIR2)
     def _():
-        _grid_pair(c8_ref, q8_ref, acc_ref, i_ref[p], j_ref[p], g=g)
+        _grid_pair(c8_ref, q8_ref, acc_ref, slab_ref, i_ref[p], j_ref[p],
+                   g=g)
 
     @pl.when(ph == PH_GLUE2)
     def _():
         x = _k_msk(x_ref[...], full_w)
         v = _k_msk(v_ref[...], full_w)
-        qv = _grid_resolve(acc_ref, full_w, pg)
+        qv = _grid_resolve(acc_ref, lb_ref, full_w)
         r_ref[...] = _barrett_glue(x, v, qv, full_w=full_w)
 
 
@@ -899,27 +1021,19 @@ def _launch_grid(kernel, sched, arrays, cols, out_widths, pg: int,
     scalar prefetch, operand tiles / accumulator in VMEM scratch."""
     batch = arrays[0].shape[0]
     bb = _grid_block_b(batch, bytes_per_instance)
-    bp = -(-batch // bb) * bb
-    ins = [_pad2(a, pg) for a in arrays] + [_col(c, batch) for c in cols]
-    if bp > batch:
-        ins = [jnp.concatenate(
-            [a, jnp.zeros((bp - batch,) + a.shape[1:], a.dtype)])
-            for a in ins]
+    ins = _fold(arrays, cols, pg, bb)
+    nr = ins[0].shape[0]
     ph, ii, jj = sched
-    n_arr = len(arrays)
-    in_specs = (
-        [pl.BlockSpec((bb, pg), lambda b, p, ph, i, j: (b, 0))
-         for _ in range(n_arr)] +
-        [pl.BlockSpec((bb, 1), lambda b, p, ph, i, j: (b, 0))
-         for _ in cols])
-    out_specs = [pl.BlockSpec((bb, 1 if w == 1 else pg),
-                              lambda b, p, ph, i, j: (b, 0))
-                 for w in out_widths]
-    out_shape = [jax.ShapeDtypeStruct((bp, 1 if w == 1 else pg), _I)
-                 for w in out_widths]
+    in_specs = [pl.BlockSpec((None, bb, a.shape[-1]),
+                             lambda b, p, ph, i, j: (b, 0, 0))
+                for a in ins]
+    widths = [1 if w == 1 else pg for w in out_widths]
+    out_specs = [pl.BlockSpec((None, bb, w), lambda b, p, ph, i, j: (b, 0, 0))
+                 for w in widths]
+    out_shape = [jax.ShapeDtypeStruct((nr, bb, w), _I) for w in widths]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(bp // bb, len(ph)),
+        grid=(nr, len(ph)),
         in_specs=in_specs,
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         scratch_shapes=scratch_fn(bb),
@@ -928,11 +1042,9 @@ def _launch_grid(kernel, sched, arrays, cols, out_widths, pg: int,
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
-        interpret=_interp(),
+        interpret=K.interpret_mode(),
     )(jnp.asarray(ph), jnp.asarray(ii), jnp.asarray(jj), *ins)
-    outs = outs if isinstance(outs, (list, tuple)) else (outs,)
-    return [o[:batch, 0] if w == 1 else o[:batch, :w].astype(DTYPE)
-            for o, w in zip(outs, out_widths)]
+    return _unfold(outs, batch, out_widths)
 
 
 def _as_cv(batched, n_out: int):
@@ -1073,7 +1185,7 @@ def correct_dispatch(full_w: int) -> tuple[str, int]:
     `correct_pallas`/`barrett_pallas`, exported so KernelPlan and the
     benchmarks report the dispatch the kernel performs rather than
     re-deriving it."""
-    pg = _rup(2 * full_w, 64)
+    pg = _rup(2 * full_w, BLOCK_T)
     return K.fused_path(2 * full_w, full_w, full_w, pg), pg
 
 
@@ -1084,12 +1196,14 @@ def _powdiff_grid_cv(win: int, full_w: int, pg: int):
     sched = _grid_schedule(pairs)
     kern = functools.partial(_powdiff_grid_kernel, win=win, full_w=full_w,
                              pg=pg, g=g)
-    bpi = _grid_bytes(pg, nba + nbb, ns * s_w)
+    bpi = _grid_bytes(pg, nba + nbb + 3 * g, ns * s_w)
 
     def scratch(bb):
-        return [pltpu.VMEM((bb, nba, BLOCK_T), _I),
-                pltpu.VMEM((bb, nbb, BLOCK_T), _I),
-                pltpu.VMEM((bb, ns, s_w), _I)]
+        return [pltpu.VMEM((nba, bb, BLOCK_T), _I),
+                pltpu.VMEM((nbb, bb, BLOCK_T), _I),
+                pltpu.VMEM((ns, bb, s_w), _I),
+                pltpu.VMEM((3 * g, bb, BLOCK_T), _I),
+                pltpu.VMEM((bb, pg), _I)]
 
     def batched(v, w, hpd, lpd, s):
         sign, x = _launch_grid(kern, sched, (v, w), (hpd, lpd, s),
@@ -1106,12 +1220,14 @@ def _update_grid_cv(win: int, full_w: int, pg: int):
     sched = _grid_schedule(pairs)
     kern = functools.partial(_update_grid_kernel, win=win, full_w=full_w,
                              pg=pg, g=g)
-    bpi = _grid_bytes(pg, nba + nbb, ns * s_w)
+    bpi = _grid_bytes(pg, nba + nbb + 3 * g, ns * s_w)
 
     def scratch(bb):
-        return [pltpu.VMEM((bb, nba, BLOCK_T), _I),
-                pltpu.VMEM((bb, nbb, BLOCK_T), _I),
-                pltpu.VMEM((bb, ns, s_w), _I)]
+        return [pltpu.VMEM((nba, bb, BLOCK_T), _I),
+                pltpu.VMEM((nbb, bb, BLOCK_T), _I),
+                pltpu.VMEM((ns, bb, s_w), _I),
+                pltpu.VMEM((3 * g, bb, BLOCK_T), _I),
+                pltpu.VMEM((bb, pg), _I)]
 
     def batched(w, x, sign, h, m, act):
         (out,) = _launch_grid(kern, sched, (w, x), (sign, h, m, act),
@@ -1127,15 +1243,17 @@ def _two_product_scratch(full_w: int, pg: int):
     g, pairs1, pairs2, nba, nbb, nbc, nbq, ns = _correct_grid_geom(full_w)
     s_w = g * BLOCK_T
     sched = _grid_schedule(pairs1, pairs2)
-    bpi = _grid_bytes(pg, nba + nbb + nbc + nbq, ns * s_w) + 4 * pg
+    bpi = _grid_bytes(pg, nba + nbb + nbc + nbq + 3 * g, ns * s_w) + 4 * pg
 
     def scratch(bb):
-        return [pltpu.VMEM((bb, nba, BLOCK_T), _I),
-                pltpu.VMEM((bb, nbb, BLOCK_T), _I),
-                pltpu.VMEM((bb, nbc, BLOCK_T), _I),
-                pltpu.VMEM((bb, nbq, BLOCK_T), _I),
+        return [pltpu.VMEM((nba, bb, BLOCK_T), _I),
+                pltpu.VMEM((nbb, bb, BLOCK_T), _I),
+                pltpu.VMEM((nbc, bb, BLOCK_T), _I),
+                pltpu.VMEM((nbq, bb, BLOCK_T), _I),
                 pltpu.VMEM((bb, pg), _I),
-                pltpu.VMEM((bb, ns, s_w), _I)]
+                pltpu.VMEM((ns, bb, s_w), _I),
+                pltpu.VMEM((3 * g, bb, BLOCK_T), _I),
+                pltpu.VMEM((bb, pg), _I)]
 
     return g, sched, scratch, bpi
 
@@ -1180,7 +1298,7 @@ def _barrett_grid_cv(full_w: int, pg: int, h: int):
 def step_pallas(v, w, *, h, m, l, s, active, g: int, win: int):
     """One Refine iteration in two batched Pallas launches."""
     full_w = v.shape[-1]
-    pg = max(_rup(2 * win, 64), _rup(full_w, 64))
+    pg = max(_rup(2 * win, BLOCK_T), _rup(full_w, BLOCK_T))
     grid = K.fused_path(2 * win, win, win, pg) == "grid"
     pd_cv = (_powdiff_grid_cv if grid else _powdiff_cv)(win, full_w, pg)
     up_cv = (_update_grid_cv if grid else _update_cv)(win, full_w, pg)

@@ -90,6 +90,13 @@ def default_impl() -> str:
     return DEFAULT_IMPL
 
 
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run in interpret mode: everywhere
+    but on a TPU backend (CPU validation).  Read at trace time; a test
+    that compiles the kernels for a described TPU patches it."""
+    return jax.default_backend() != "tpu"
+
+
 def set_default_impl(name: str) -> None:
     global DEFAULT_IMPL
     if name not in IMPLS:
@@ -150,13 +157,16 @@ def fallback_chain(name: str) -> list[str]:
 # Unrolled-path ceilings, derived from hardware budgets:
 #  * pairs: every (i, j) block pair is a dot_general unrolled in the
 #    kernel body; past ~256 the Mosaic compile time dominates.
-#  * VMEM: the unrolled body keeps ~12 full-width limb arrays plus ~6
+#  * VMEM: the unrolled body keeps every v tile's (T, 2T) bf16
+#    Toeplitz tile live, plus ~12 full-width limb arrays and ~6
 #    sub-digit-width arrays (operands, diagonal tiles, resolve
-#    temporaries) live per instance, and the batched launch runs up to
-#    MAX_BLOCK_B = 16 instances per grid step; the estimate must fit
-#    in half a ~16 MiB TPU core, leaving the other half as slack.
+#    temporaries) per instance, and the batched launch runs up to
+#    MAX_BLOCK_B = 16 instances per grid step.  The v5e Mosaic
+#    compiler's scoped-VMEM need at bb = 16 was 3.15 / 8.98 / 15.88 /
+#    22.11 MB at 264 / 520 / 776 / 1024-limb widths, against its
+#    default 16 MiB limit; the estimate may use three quarters of it.
 FUSED_UNROLL_MAX_PAIRS = 256
-FUSED_VMEM_BUDGET = 8 << 20
+FUSED_VMEM_BUDGET = 12 << 20
 _FUSED_LIMB_BUFS = 12
 _FUSED_SUB_BUFS = 6
 
@@ -204,8 +214,9 @@ def fused_path(out_width: int, cu: int, cv: int, pg: int) -> str:
     if pairs > FUSED_UNROLL_MAX_PAIRS:
         return "grid"
     n8r = (min(nu + nv - 1, d_keep) + 1) * t
-    est = 4 * bigmul.MAX_BLOCK_B * (_FUSED_LIMB_BUFS * pg
-                                    + _FUSED_SUB_BUFS * n8r)
+    est = bigmul.MAX_BLOCK_B * (nv * 2 * t * 2 * t       # bf16 Toeplitz
+                                + 4 * (_FUSED_LIMB_BUFS * pg
+                                       + _FUSED_SUB_BUFS * n8r))
     return "grid" if est > FUSED_VMEM_BUDGET else "unrolled"
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.models import transformer as T
+from repro.utils import compile_cache
 
 
 def serve_lm(args):
@@ -73,4 +74,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -29,6 +29,8 @@ from typing import NamedTuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.utils import compat
+
 
 def resolve_impl(impl: str | None) -> str:
     """Concrete impl name for an optional override (None = backend
@@ -103,6 +105,12 @@ def kernel_plan(bucket: int, w_limbs: int,
                       step_glue_ops=CM.step_glue_ops(impl))
 
 
+def device_rows(bucket: int, mesh) -> int:
+    """Rows each device's kernels launch with: the whole bucket, or
+    under a mesh (`sharded_jit`) an even share of it."""
+    return bucket if mesh is None else bucket // mesh.size
+
+
 class Batcher:
     """Plans how a request of size n maps onto compiled bucket sizes.
 
@@ -137,24 +145,28 @@ def pad_ints(xs, bucket: int, fill: int) -> list:
     return list(xs) + [fill] * (bucket - len(xs))
 
 
-def batch_sharding(mesh) -> NamedSharding:
-    """Shard the leading (batch) axis across every mesh axis."""
-    return NamedSharding(mesh, P(tuple(mesh.axis_names), None))
-
-
 def sharded_jit(fn, mesh, batched_argnums, n_args: int, n_out: int = 1):
     """jit `fn`; under a mesh, shard the batched args and all outputs on
     the batch axis and replicate the rest (e.g. a cached BarrettContext,
-    which is a pytree -- the replicated sharding applies to its leaves).
+    which is a pytree -- the replicated spec applies to its leaves).
+
+    XLA cannot partition a Pallas (Mosaic) kernel, so under a mesh `fn`
+    runs inside a shard_map: every device computes its own rows (they
+    are independent) and no collective is needed.  The batch must
+    divide evenly over the mesh.
     """
     if mesh is None:
         return jax.jit(fn)
-    sh = batch_sharding(mesh)
-    rep = NamedSharding(mesh, P())
+    row = P(tuple(mesh.axis_names))
     batched = set(batched_argnums)
-    in_sh = tuple(sh if i in batched else rep for i in range(n_args))
-    out_sh = sh if n_out == 1 else tuple(sh for _ in range(n_out))
-    return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
+    in_specs = tuple(row if i in batched else P() for i in range(n_args))
+    out_specs = row if n_out == 1 else (row,) * n_out
+    local = compat.shard_map(fn, mesh, in_specs, out_specs)
+    rows = NamedSharding(mesh, row)
+    return jax.jit(
+        local,
+        in_shardings=tuple(NamedSharding(mesh, s) for s in in_specs),
+        out_shardings=rows if n_out == 1 else (rows,) * n_out)
 
 
 class ServiceMetrics:

@@ -83,7 +83,8 @@ class BigintDivisionService:
             self._fire("compile", op="divmod", bucket=bucket, impl=eff)
             # plan against the widest internal product: divmod pads to
             # m + PAD limbs and forms the double-width u * shinv there
-            plan = BT.kernel_plan(bucket, self.m + S.PAD, eff)
+            plan = BT.kernel_plan(BT.device_rows(bucket, self.mesh),
+                                  self.m + S.PAD, eff)
             req = BT.resolve_impl(self.impl)
             if eff != req:
                 plan = plan._replace(degraded_from=req)

@@ -154,7 +154,8 @@ class ModArithService:
         def build():
             self._fire("compile", op=op, bucket=bucket, impl=eff)
             # widest internal product: x * mu at the Barrett working width
-            plan = BT.kernel_plan(bucket, MA.barrett_width(self.m), eff)
+            plan = BT.kernel_plan(BT.device_rows(bucket, self.mesh),
+                                  MA.barrett_width(self.m), eff)
             req = BT.resolve_impl(self.impl)
             if eff != req:
                 plan = plan._replace(degraded_from=req)

@@ -1,4 +1,4 @@
-"""Version shims for JAX API drift."""
+"""Thin wrappers over JAX APIs the repo calls from several places."""
 
 from __future__ import annotations
 
@@ -6,12 +6,6 @@ import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` (new API) with fallback to
-    `jax.experimental.shard_map.shard_map` (<= 0.4.x), where the
-    replication-check kwarg is named `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """`jax.shard_map` with the replication check off by default."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

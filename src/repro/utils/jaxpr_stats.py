@@ -37,6 +37,7 @@ from __future__ import annotations
 from collections import Counter
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def _sub_jaxprs(params):
@@ -44,9 +45,9 @@ def _sub_jaxprs(params):
     for val in params.values():
         vals = val if isinstance(val, (list, tuple)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, Jaxpr):
                 yield v
 
 
@@ -61,7 +62,7 @@ def iter_eqns(jaxpr, into_kernels: bool = True):
     other eqn is yielded AND has its param jaxprs walked (pjit, scan,
     cond, custom_vmap, remat, ...).  An empty jaxpr yields nothing.
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -100,7 +101,7 @@ def runtime_pallas_launches(jaxpr) -> int:
     and what the cost model's `obs/costmodel.py:modexp_launches`
     predicts.  While-loop bodies count once (static lower bound);
     cond still counts every branch (upper bound)."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     total = 0
     for eqn in jaxpr.eqns:

@@ -108,6 +108,28 @@ def test_bigint_service_exact_and_splitting():
         assert (qq, rr) == divmod(u, v)
 
 
+def test_services_under_a_mesh_run_per_device_rows():
+    """Under a mesh the bucket runs in a shard_map (Pallas kernels
+    cannot be partitioned by jit shardings): results stay exact and
+    each KernelPlan describes one device's share of the bucket."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from repro.serving.bigint_service import BigintDivisionService
+    from repro.serving.modexp_service import ModArithService
+    mesh = Mesh(np.array(jax.devices()[:1]), ("chips",))
+    rnd = random.Random(4)
+    m = 8
+    div = BigintDivisionService(m_limbs=m, mesh=mesh, batch_buckets=(4,))
+    us = [rnd.randint(0, 2 ** (16 * m) - 1) for _ in range(3)]
+    vs = [rnd.randint(1, 2 ** (16 * m // 2) - 1) for _ in range(3)]
+    q, r = div.divide(us, vs)
+    assert list(zip(q, r)) == [divmod(u, v) for u, v in zip(us, vs)]
+    assert div.kernel_plans[4].grid_rows == 4 // mesh.size
+    mod = ModArithService(m_limbs=4, mesh=mesh, batch_buckets=(2,))
+    v = 1000003
+    assert mod.modexp([7, 9], [12, 0], v) == [pow(7, 12, v), 1]
+
+
 @given(st.integers(0, 2 ** 512 - 1), st.integers(1, 2 ** 256 - 1))
 @settings(max_examples=25, deadline=None)
 def test_windowed_divmod_property(u, v):
