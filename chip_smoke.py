@@ -99,7 +99,8 @@ def frontend_checks(fe, n_requests: int) -> None:
     total = fe._counter_total
     check(total(m.degraded) == 0, "frontend degraded_total > 0")
     check(total(m.faults) == 0, "frontend counted chunk faults")
-    check(total(m.batches) < n_requests, "requests did not coalesce")
+    cycles = sum(s.count for s in m.coalesced.series())
+    check(cycles < n_requests, "requests did not coalesce")
     check(fe.dropped_requests() == 0, "frontend dropped requests")
 
 

@@ -62,7 +62,6 @@ import jax.numpy as jnp
 from .bigint import BASE, LOG_BASE, MASK, DTYPE, one_hot_pow
 from . import arith as A
 from repro.kernels import ops as K
-from repro.obs import telemetry as OBS
 
 _U = jnp.uint32
 _I = jnp.int32
@@ -137,15 +136,12 @@ def _refine(v, h, k, w, *, width, iters_max, impl, windowed=True):
     for i in range(iters_max):
         wi = min(max(32, 2 ** (i + 1) + 16), width) if windowed else width
         active = i < need
-        # trace-time profiler attribution (no-op unless
-        # obs.telemetry.set_profiling(True); names the iteration's
-        # launches in profiler timelines / Mosaic dumps)
-        with OBS.scope(f"refine/iter{i:02d}_win{wi}"):
-            m = jnp.clip(jnp.minimum(hk + 1 - l, l), 0, None)
-            s = jnp.maximum(0, k - 2 * l + 1 - g)
-            w = K.fused_step(v, w, h=k + l + m - s + g, m=m, l=l, s=s,
-                             active=active, g=g, win=wi, impl=impl)
-            l = jnp.where(active, l + m - 1, l)
+        m = jnp.clip(jnp.minimum(hk + 1 - l, l), 0, None)
+        s = jnp.maximum(0, k - 2 * l + 1 - g)
+        w = K.fused_step(v, w, h=k + l + m - s + g, m=m, l=l, s=s,
+                         active=active, g=g, win=wi, impl=impl,
+                         name=f"refine_i{i:02d}_w{wi}")
+        l = jnp.where(active, l + m - 1, l)
     return A.shift(w, h - k - l - g)
 
 

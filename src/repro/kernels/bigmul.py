@@ -162,6 +162,7 @@ def _call_pair_kernel(u8b, toep, i_idx, j_idx, d_idx, first, ndiag, t):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((ndiag, 2 * t), _I),
         interpret=K.interpret_mode(),
+        name="bigmul",
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), jnp.asarray(d_idx),
       jnp.asarray(first), u8b, toep)
 
@@ -420,6 +421,7 @@ def mul_pallas_batched(u: jax.Array, v: jax.Array, out_width: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nr, ndiag, bb, 3 * t), _I),
         interpret=K.interpret_mode(),
+        name="bigmul_batched",
     )(jnp.asarray(i_idx), jnp.asarray(j_idx), jnp.asarray(d_idx),
       jnp.asarray(first), jnp.asarray(last), u8b, v8b)
     seg = seg.transpose(0, 2, 1, 3).reshape(bp, ndiag, 3 * t)

@@ -55,6 +55,12 @@ pallas_calls, divmod finalization = 1, Barrett reduction = 1; a full
 divmod_batch is 2*iters + 1 launches with ZERO full-width XLA glue
 ops between them.
 
+Kernel names (`pallas_call(name=...)`: the instruction's name in the
+compiled program and the op's name in a device profile): by stage,
+`refine_i<ii>_w<win>_powdiff` / `_update` for Refine iteration ii at
+window win (`core/shinv.py:_refine`), `divmod_correct`, `barrett`,
+each with `_grid` appended on the grid generation.
+
 Zero-divisor contract (both generations, fused and reference):
 divmod(u, 0) = (0, u) and shinv(0, h) = 0, applied inside
 `_correct_glue`'s v == 0 select -- see core/shinv.py.
@@ -604,10 +610,11 @@ def _unfold(outs, batch: int, out_widths):
     return res
 
 
-def _launch(kernel, arrays, cols, out_widths, pg: int):
-    """pallas_call a fused kernel over the batch as the leading grid
-    axis: BLOCK_B instances per step, whole (bb, pg) operands in VMEM,
-    per-instance scalars as (bb, 1) columns."""
+def _launch(kernel, arrays, cols, out_widths, pg: int, name: str):
+    """pallas_call a fused kernel, named `name` in the compiled program
+    and in profiles, over the batch as the leading grid axis: BLOCK_B
+    instances per step, whole (bb, pg) operands in VMEM, per-instance
+    scalars as (bb, 1) columns."""
     batch = arrays[0].shape[0]
     bb = pick_block_b(batch)
     ins = _fold(arrays, cols, pg, bb)
@@ -625,6 +632,7 @@ def _launch(kernel, arrays, cols, out_widths, pg: int):
         out_specs=out_specs if len(out_specs) > 1 else out_specs[0],
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
         interpret=K.interpret_mode(),
+        name=name,
     )(*ins)
     return _unfold(outs, batch, out_widths)
 
@@ -1014,11 +1022,12 @@ def _barrett_grid_kernel(ph_ref, i_ref, j_ref,
 
 
 def _launch_grid(kernel, sched, arrays, cols, out_widths, pg: int,
-                 scratch_fn, bytes_per_instance: int):
-    """pallas_call a grid-scheduled fused kernel: grid = (batch blocks,
-    phase-tape steps), full-width operands resident per batch block
-    (index maps constant over the step axis), the tape in SMEM via
-    scalar prefetch, operand tiles / accumulator in VMEM scratch."""
+                 scratch_fn, bytes_per_instance: int, name: str):
+    """pallas_call a grid-scheduled fused kernel named `name`: grid =
+    (batch blocks, phase-tape steps), full-width operands resident per
+    batch block (index maps constant over the step axis), the tape in
+    SMEM via scalar prefetch, operand tiles / accumulator in VMEM
+    scratch."""
     batch = arrays[0].shape[0]
     bb = _grid_block_b(batch, bytes_per_instance)
     ins = _fold(arrays, cols, pg, bb)
@@ -1043,6 +1052,7 @@ def _launch_grid(kernel, sched, arrays, cols, out_widths, pg: int,
         grid_spec=grid_spec,
         out_shape=out_shape if len(out_shape) > 1 else out_shape[0],
         interpret=K.interpret_mode(),
+        name=name,
     )(jnp.asarray(ph), jnp.asarray(ii), jnp.asarray(jj), *ins)
     return _unfold(outs, batch, out_widths)
 
@@ -1066,11 +1076,12 @@ def _as_cv(batched, n_out: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _powdiff_cv(win: int, full_w: int, pg: int):
+def _powdiff_cv(win: int, full_w: int, pg: int, name: str):
     kern = functools.partial(_powdiff_kernel, win=win, full_w=full_w, pg=pg)
 
     def batched(v, w, hpd, lpd, s):
-        sign, x = _launch(kern, (v, w), (hpd, lpd, s), (1, full_w), pg)
+        sign, x = _launch(kern, (v, w), (hpd, lpd, s), (1, full_w), pg,
+                          name)
         return sign != 0, x
 
     @jax.custom_batching.custom_vmap
@@ -1086,11 +1097,12 @@ def _powdiff_cv(win: int, full_w: int, pg: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _update_cv(win: int, full_w: int, pg: int):
+def _update_cv(win: int, full_w: int, pg: int, name: str):
     kern = functools.partial(_update_kernel, win=win, full_w=full_w, pg=pg)
 
     def batched(w, x, sign, h, m, act):
-        (out,) = _launch(kern, (w, x), (sign, h, m, act), (full_w,), pg)
+        (out,) = _launch(kern, (w, x), (sign, h, m, act), (full_w,), pg,
+                         name)
         return out
 
     @jax.custom_batching.custom_vmap
@@ -1110,7 +1122,8 @@ def _correct_cv(full_w: int, pg: int):
     kern = functools.partial(_correct_kernel, full_w=full_w, pg=pg)
 
     def batched(u, v, si, h):
-        q, r = _launch(kern, (u, v, si), (h,), (full_w, full_w), pg)
+        q, r = _launch(kern, (u, v, si), (h,), (full_w, full_w), pg,
+                       "divmod_correct")
         return q, r
 
     @jax.custom_batching.custom_vmap
@@ -1130,7 +1143,7 @@ def _barrett_cv(full_w: int, pg: int, h: int):
     kern = functools.partial(_barrett_kernel, h=h, full_w=full_w, pg=pg)
 
     def batched(x, mu, v):
-        (r,) = _launch(kern, (x, mu, v), (), (full_w,), pg)
+        (r,) = _launch(kern, (x, mu, v), (), (full_w,), pg, "barrett")
         return r
 
     @jax.custom_batching.custom_vmap
@@ -1190,7 +1203,7 @@ def correct_dispatch(full_w: int) -> tuple[str, int]:
 
 
 @functools.lru_cache(maxsize=None)
-def _powdiff_grid_cv(win: int, full_w: int, pg: int):
+def _powdiff_grid_cv(win: int, full_w: int, pg: int, name: str):
     g, pairs, nba, nbb, ns = _step_grid_geom(win)
     s_w = g * BLOCK_T
     sched = _grid_schedule(pairs)
@@ -1207,14 +1220,14 @@ def _powdiff_grid_cv(win: int, full_w: int, pg: int):
 
     def batched(v, w, hpd, lpd, s):
         sign, x = _launch_grid(kern, sched, (v, w), (hpd, lpd, s),
-                               (1, full_w), pg, scratch, bpi)
+                               (1, full_w), pg, scratch, bpi, name)
         return sign != 0, x
 
     return _as_cv(batched, 2)
 
 
 @functools.lru_cache(maxsize=None)
-def _update_grid_cv(win: int, full_w: int, pg: int):
+def _update_grid_cv(win: int, full_w: int, pg: int, name: str):
     g, pairs, nba, nbb, ns = _step_grid_geom(win)
     s_w = g * BLOCK_T
     sched = _grid_schedule(pairs)
@@ -1231,7 +1244,7 @@ def _update_grid_cv(win: int, full_w: int, pg: int):
 
     def batched(w, x, sign, h, m, act):
         (out,) = _launch_grid(kern, sched, (w, x), (sign, h, m, act),
-                              (full_w,), pg, scratch, bpi)
+                              (full_w,), pg, scratch, bpi, name)
         return out
 
     return _as_cv(batched, 1)
@@ -1266,7 +1279,8 @@ def _correct_grid_cv(full_w: int, pg: int):
 
     def batched(u, v, si, h):
         q, r = _launch_grid(kern, sched, (u, v, si), (h,),
-                            (full_w, full_w), pg, scratch, bpi)
+                            (full_w, full_w), pg, scratch, bpi,
+                            "divmod_correct_grid")
         return q, r
 
     return _as_cv(batched, 2)
@@ -1280,7 +1294,7 @@ def _barrett_grid_cv(full_w: int, pg: int, h: int):
 
     def batched(x, mu, v):
         (r,) = _launch_grid(kern, sched, (x, mu, v), (), (full_w,), pg,
-                            scratch, bpi)
+                            scratch, bpi, "barrett_grid")
         return r
 
     return _as_cv(batched, 1)
@@ -1295,13 +1309,19 @@ def _barrett_grid_cv(full_w: int, pg: int, h: int):
 # bit-identical.
 # ---------------------------------------------------------------------------
 
-def step_pallas(v, w, *, h, m, l, s, active, g: int, win: int):
-    """One Refine iteration in two batched Pallas launches."""
+def step_pallas(v, w, *, h, m, l, s, active, g: int, win: int,
+                name: str = "refine"):
+    """One Refine iteration in two batched Pallas launches, named
+    `<name>_powdiff` and `<name>_update` (`_grid` appended on the grid
+    generation)."""
     full_w = v.shape[-1]
     pg = max(_rup(2 * win, BLOCK_T), _rup(full_w, BLOCK_T))
     grid = K.fused_path(2 * win, win, win, pg) == "grid"
-    pd_cv = (_powdiff_grid_cv if grid else _powdiff_cv)(win, full_w, pg)
-    up_cv = (_update_grid_cv if grid else _update_cv)(win, full_w, pg)
+    gen = "_grid" if grid else ""
+    pd_cv = (_powdiff_grid_cv if grid else _powdiff_cv)(
+        win, full_w, pg, f"{name}_powdiff{gen}")
+    up_cv = (_update_grid_cv if grid else _update_cv)(
+        win, full_w, pg, f"{name}_update{gen}")
     hpd = jnp.asarray(h - m, _I)
     lpd = jnp.asarray(l - g, _I)
     sign, x = pd_cv(v, w, hpd, lpd, jnp.asarray(s, _I))

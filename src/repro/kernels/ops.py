@@ -429,7 +429,7 @@ def mul_batch_jit(u, v, out_width: int, impl: str | None = None):
 # ---------------------------------------------------------------------------
 
 def fused_step(v, w, *, h, m, l, s, active, g: int, win: int,
-               impl: str | None = None):
+               impl: str | None = None, name: str = "refine"):
     """One guarded Refine iteration on the full-width iterate.
 
     v, w: (W,) limb vectors (w is the current iterate, already guard-
@@ -438,15 +438,14 @@ def fused_step(v, w, *, h, m, l, s, active, g: int, win: int,
     this iteration (win == W when not windowed).  Returns the updated
     full-width iterate (the -1 normalization shift and the
     active-instance select are folded in).  Batch with jax.vmap: the
-    pallas_fused path routes the whole batch into 2 native launches.
+    pallas_fused path routes the whole batch into 2 native launches,
+    named after `name` (`fused.step_pallas`).
     """
     from . import fused
-    from repro.obs import telemetry as OBS
     impl = impl or default_impl()
     if impl == "pallas_fused":
-        with OBS.scope("fused_step"):
-            return fused.step_pallas(v, w, h=h, m=m, l=l, s=s,
-                                     active=active, g=g, win=win)
+        return fused.step_pallas(v, w, h=h, m=m, l=l, s=s, active=active,
+                                 g=g, win=win, name=name)
     return fused.step_reference(v, w, h=h, m=m, l=l, s=s, active=active,
                                 g=g, win=win, impl=impl)
 
@@ -458,11 +457,9 @@ def fused_correct(u, v, si, *, h, impl: str | None = None):
     the documented total extension (q, r) = (0, u).  One batched Pallas
     launch under impl="pallas_fused"."""
     from . import fused
-    from repro.obs import telemetry as OBS
     impl = impl or default_impl()
     if impl == "pallas_fused":
-        with OBS.scope("fused_correct"):
-            return fused.correct_pallas(u, v, si, h=h)
+        return fused.correct_pallas(u, v, si, h=h)
     return fused.correct_reference(u, v, si, h=h, impl=impl)
 
 
@@ -472,9 +469,7 @@ def fused_barrett(x, mu, v, *, h: int, impl: str | None = None):
     width W (caller slices to the modulus width).  One batched Pallas
     launch under impl="pallas_fused"."""
     from . import fused
-    from repro.obs import telemetry as OBS
     impl = impl or default_impl()
     if impl == "pallas_fused":
-        with OBS.scope("fused_barrett"):
-            return fused.barrett_pallas(x, mu, v, h=h)
+        return fused.barrett_pallas(x, mu, v, h=h)
     return fused.barrett_reference(x, mu, v, h=h, impl=impl)

@@ -3,8 +3,9 @@
 Three deliberately small modules:
 
   telemetry   dependency-free counters/gauges/histograms with labeled
-              series, a monotonic timer, JSON / line-protocol export,
-              and optional jax profiler hooks (no-op by default).
+              series, JSON / line-protocol export, and `span`: a
+              host span on the profiler's clock that can also time
+              into a histogram, always on.
   costmodel   the paper's multiplication/launch cost model as ONE
               importable source of truth -- `kernels/fused.py` and
               `serving/batching.kernel_plan` re-export their

@@ -1,5 +1,6 @@
 """Dependency-free metrics core: counters, gauges, histograms with
-labeled series, a monotonic timer, and JSON / line-protocol export.
+labeled series, JSON / line-protocol export, and host spans on the
+profiler's clock.
 
 Design constraints (and why):
 
@@ -13,10 +14,9 @@ Design constraints (and why):
     Recording a traced value would silently bake one trace's sample
     into the executable; the registry only accepts plain Python
     numbers (`float()` coercion raises on tracers).
-  * stdlib only at import time.  The optional jax profiler hooks at
-    the bottom import jax lazily and default to no-ops, so this module
-    is importable (and the CI docs tooling can use it) without a
-    backend.
+  * stdlib only at import time.  `span` imports `jax.profiler` on
+    first use, so this module is importable (and the CI docs tooling
+    can use it) without a backend.
 
 Label model: a metric is declared once with a fixed tuple of label
 NAMES; each distinct label-value assignment is one monotonic series
@@ -29,6 +29,7 @@ by metric name, then label values) in two formats: `Registry.to_json`
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import time
 
@@ -252,77 +253,56 @@ class Registry:
         return lines
 
 
-def merged_collect(*registries) -> list[dict]:
-    """One deterministic dump across several registries (e.g. a
-    serving frontend's queue/failure families next to the wrapped
-    service's request families).  Families are concatenated in
-    name-sorted order; name collisions are kept as separate entries
-    (distinct owners are distinct series sources by design -- the
-    registry model has no global singletons to merge into)."""
-    fams = [fam for reg in registries for fam in reg.collect()]
-    return sorted(fams, key=lambda f: f["name"])
-
-
 def merged_lines(*registries) -> list[str]:
-    """Line-protocol export across several registries (see
-    `merged_collect`); the serving tier's one-stop metric export."""
+    """Line-protocol export across several registries (e.g. a serving
+    frontend's queue/failure families next to the wrapped service's
+    request families); the serving tier's one-stop metric export."""
     out = []
     for reg in registries:
         out.extend(reg.to_lines())
     return out
 
 
-@contextlib.contextmanager
-def timer():
-    """Standalone monotonic timer: `with timer() as t: ...; t.seconds`."""
-    class _T:
-        seconds = 0.0
-    t = _T()
-    t0 = time.perf_counter()
-    try:
-        yield t
-    finally:
-        t.seconds = time.perf_counter() - t0
+@functools.cache
+def _trace_annotation():
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
-# ---------------------------------------------------------------------------
-# optional jax profiler hooks
-#
-# Disabled by default: `scope`/`annotate` return null context managers,
-# so instrumented code paths (shinv Refine iterations, fused-stage
-# dispatch, service endpoints) trace byte-identically with profiling
-# off.  `set_profiling(True)` turns them into jax.named_scope (trace-
-# time metadata: names kernels/launches in XLA/Mosaic dumps and
-# profiler timelines) and jax.profiler.TraceAnnotation (host-side
-# runtime spans around compiled calls), so a real-hardware session
-# gets attributable traces without touching call sites.
-# ---------------------------------------------------------------------------
+class span:
+    """A host span on the profiler's clock, always on.
 
-_PROFILING = False
+    From construction until `end()` (or the end of a `with` block) the
+    span is a `jax.profiler.TraceAnnotation` named `name`, so a
+    profiler session records it on the same clock as the device ops;
+    with no session active it costs a few microseconds.  Given a
+    histogram series, or a tuple of them, it also observes its length
+    in seconds on each.
 
+    Host-side only: never open one inside traced code.  `end()` may
+    come from a later coroutine on the same thread (two spans may end
+    out of order), and only its first call counts."""
 
-def set_profiling(enabled: bool) -> None:
-    global _PROFILING
-    _PROFILING = bool(enabled)
+    __slots__ = ("_annotation", "_series", "_t0")
 
+    def __init__(self, name: str, series=None):
+        self._series = (() if series is None else
+                        series if isinstance(series, tuple) else (series,))
+        self._annotation = _trace_annotation()(name)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
 
-def profiling_enabled() -> bool:
-    return _PROFILING
+    def end(self) -> None:
+        if self._annotation is None:
+            return
+        seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        for s in self._series:
+            s.observe(seconds)
 
+    def __enter__(self) -> "span":
+        return self
 
-def scope(name: str):
-    """Trace-time name scope (use INSIDE traced code).  No-op unless
-    profiling is enabled."""
-    if not _PROFILING:
-        return contextlib.nullcontext()
-    import jax
-    return jax.named_scope(name)
-
-
-def annotate(name: str):
-    """Host-side runtime trace span (use AROUND compiled calls, never
-    inside a trace).  No-op unless profiling is enabled."""
-    if not _PROFILING:
-        return contextlib.nullcontext()
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+    def __exit__(self, *exc) -> None:
+        self.end()

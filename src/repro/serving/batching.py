@@ -29,6 +29,7 @@ from typing import NamedTuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.obs import telemetry as T
 from repro.utils import compat
 
 
@@ -176,10 +177,12 @@ class ServiceMetrics:
     and exported series are uniform (docs/observability.md documents
     the names/labels).  All recording happens host-side around the
     compiled per-bucket calls -- nothing here touches traced values.
+    Each phase of a call is a `telemetry.span` named `service.<phase>`
+    (validate, pack, execute, unpack, precompute, profile), on the
+    profiler's clock and in `phase_seconds{op, phase}`.
     """
 
     def __init__(self):
-        from repro.obs import telemetry as T
         self.registry = T.Registry()
         self._requests = self.registry.counter(
             "requests_total", "service endpoint calls", ("op",))
@@ -194,14 +197,26 @@ class ServiceMetrics:
         self._latency = self.registry.histogram(
             "bucket_seconds", "per-bucket execution wall time",
             ("op", "bucket"))
+        self._phases = self.registry.histogram(
+            "phase_seconds", "wall time of each host phase of a call",
+            ("op", "phase"))
 
     def record_request(self, op: str, n_items: int) -> None:
         self._requests.labels(op=op).inc()
         self._items.labels(op=op).inc(n_items)
 
+    def phase(self, op: str, phase: str):
+        """The `service.<phase>` span, timed into `phase_seconds`."""
+        return T.span(f"service.{phase}",
+                      self._phases.labels(op=op, phase=phase))
+
     def chunk_timer(self, op: str, bucket: int):
-        """Context manager timing one padded-bucket execution."""
-        return self._latency.labels(op=op, bucket=bucket).time()
+        """The `service.execute` span of one padded-bucket execution
+        (the compiled call and the copy of its result), timed into
+        both `phase_seconds` and `bucket_seconds`."""
+        return T.span("service.execute",
+                      (self._phases.labels(op=op, phase="execute"),
+                       self._latency.labels(op=op, bucket=bucket)))
 
     def record_rows(self, bucket: int, true_rows: int) -> None:
         self._rows_true.labels(bucket=bucket).inc(true_rows)

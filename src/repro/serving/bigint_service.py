@@ -12,7 +12,9 @@ Observability (docs/observability.md): every bucket compile captures a
 STATIC structural profile off the traced program -- Pallas launches,
 XLA glue eqns, total eqns (`utils/jaxpr_stats.trace_profile`) plus the
 `KernelPlan` -- and every request records runtime counters (requests,
-true-vs-padded rows, per-bucket latency) on a per-instance registry.
+true-vs-padded rows, per-bucket latency) on a per-instance registry,
+and each host phase of a call (validate, pack, execute, unpack) as a
+`service.<phase>` span on the profiler's clock.
 `snapshot()` merges both; `obs/report.py` renders it as a
 measured-vs-model table against the 2*iters + 1 launch contract.
 """
@@ -26,7 +28,6 @@ import jax.numpy as jnp
 
 from repro.core import bigint as bi
 from repro.core import shinv as S
-from repro.obs import telemetry as OBS
 from repro.utils import jaxpr_stats as JS
 from . import batching as BT
 from . import errors as E
@@ -70,10 +71,11 @@ class BigintDivisionService:
         if op != "divmod":
             raise E.InvalidRequest(f"unknown op {op!r} for "
                                    "BigintDivisionService")
-        n = E.check_lengths(columns, names=("us", "vs"))
-        lim = bi.BASE ** self.m
-        E.check_operands("u", columns[0], lim, f"B^{self.m}")
-        E.check_operands("v", columns[1], lim, f"B^{self.m}")
+        with self.telemetry.phase(op, "validate"):
+            n = E.check_lengths(columns, names=("us", "vs"))
+            lim = bi.BASE ** self.m
+            E.check_operands("u", columns[0], lim, f"B^{self.m}")
+            E.check_operands("v", columns[1], lim, f"B^{self.m}")
         return n
 
     def _fn(self, bucket: int, impl: str | None = None):
@@ -92,8 +94,9 @@ class BigintDivisionService:
             fn = partial(S.divmod_batch, impl=plan.impl)
             if self.capture_profiles:
                 z = jnp.zeros((bucket, self.m), jnp.uint32)
-                self.static_profiles[bucket] = {
-                    "divmod": JS.trace_profile(fn, z, z)}
+                with self.telemetry.phase("divmod", "profile"):
+                    self.static_profiles[bucket] = {
+                        "divmod": JS.trace_profile(fn, z, z)}
             return BT.sharded_jit(fn, self.mesh,
                                   batched_argnums=(0, 1), n_args=2,
                                   n_out=2)
@@ -123,21 +126,22 @@ class BigintDivisionService:
         for lo, hi, bucket in self.batcher.plan(n):
             eff = BT.resolve_impl(impl or self.impl)
             self._fire("transfer", op="divmod", bucket=bucket)
-            u_pad = BT.pad_ints(us[lo:hi], bucket, 0)
-            v_pad = BT.pad_ints(vs[lo:hi], bucket, 1)
-            ua = jnp.asarray(bi.batch_from_ints(u_pad, self.m))
-            va = jnp.asarray(bi.batch_from_ints(v_pad, self.m))
+            with self.telemetry.phase("divmod", "pack"):
+                u_pad = BT.pad_ints(us[lo:hi], bucket, 0)
+                v_pad = BT.pad_ints(vs[lo:hi], bucket, 1)
+                ua = jnp.asarray(bi.batch_from_ints(u_pad, self.m))
+                va = jnp.asarray(bi.batch_from_ints(v_pad, self.m))
             fn = self._fn(bucket, impl)
             self.telemetry.record_rows(bucket, hi - lo)
-            with OBS.annotate(f"bigint_service/divmod/b{bucket}"), \
-                    self.telemetry.chunk_timer("divmod", bucket):
+            with self.telemetry.chunk_timer("divmod", bucket):
                 self._fire("execute", op="divmod", bucket=bucket,
                            impl=eff)
                 q, r = fn(ua, va)
                 q, r = np.asarray(q), np.asarray(r)
-            keep = hi - lo
-            qs += bi.batch_to_ints(q[:keep])
-            rs += bi.batch_to_ints(r[:keep])
+            with self.telemetry.phase("divmod", "unpack"):
+                keep = hi - lo
+                qs += bi.batch_to_ints(q[:keep])
+                rs += bi.batch_to_ints(r[:keep])
         return qs, rs
 
     # -- introspection ----------------------------------------------------

@@ -39,6 +39,10 @@ millions-of-users target needs:
               set, breaker states, and drop accounting;  `snapshot()`
               merges the frontend registry, the wrapped service's
               snapshot, and the fault-injection accounting.
+  spans       each request's queue wait (admission to the hand-off of
+              its first chunk), each coalescing cycle and each scatter
+              is a `telemetry.span` on the profiler's clock
+              (docs/observability.md).
 
 Determinism: the frontend adds no randomness beyond the seeded
 backoff jitter, and with a seeded fault plan (serving/faults.py) an
@@ -116,23 +120,25 @@ class FrontendMetrics:
         self.chunks_cancelled = r.counter(
             "chunks_cancelled_total",
             "chunks skipped because every member request had expired")
-        self.batches = r.counter(
-            "batches_total", "coalescing cycles executed")
         self.coalesced = r.histogram(
             "coalesced_requests", "requests merged per batch cycle",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128))
         self.request_seconds = r.histogram(
             "request_seconds", "admission-to-resolution wall time",
             ("op",))
+        self.queue_wait = r.histogram(
+            "queue_wait_seconds",
+            "admission to the hand-off of a request's first chunk "
+            "(or to its resolution, if no chunk of it ran)", ("op",))
 
 
 class _Request:
     """One admitted request and its scatter/accounting state."""
 
     __slots__ = ("id", "op", "cols", "v", "n", "nout", "deadline",
-                 "future", "done_items", "results", "settled")
+                 "future", "done_items", "results", "settled", "wait")
 
-    def __init__(self, rid, op, cols, v, nout, deadline, future):
+    def __init__(self, rid, op, cols, v, nout, deadline, future, wait):
         self.id = rid
         self.op = op
         self.cols = cols
@@ -144,6 +150,7 @@ class _Request:
         self.done_items = 0
         self.results = [[None] * self.n for _ in range(nout)]
         self.settled = False       # accounting resolved exactly once
+        self.wait = wait           # `frontend.queue_wait` span
 
 
 class AsyncFrontend:
@@ -258,7 +265,9 @@ class AsyncFrontend:
             else self.policy.default_timeout
         deadline = None if timeout is None else self.clock() + timeout
         req = _Request(next(self._ids), op, cols, v, nout, deadline,
-                       asyncio.get_running_loop().create_future())
+                       asyncio.get_running_loop().create_future(),
+                       T.span("frontend.queue_wait",
+                              self.metrics.queue_wait.labels(op=op)))
         self._depth += 1
         self._items += n
         self._set_gauges()
@@ -277,26 +286,31 @@ class AsyncFrontend:
         assert self._queue is not None
         while True:
             req = await self._queue.get()
-            if self.policy.coalesce_window > 0:
-                await asyncio.sleep(self.policy.coalesce_window)
-            batch = [req]
-            while len(batch) < self.policy.max_batch_requests:
-                try:
-                    batch.append(self._queue.get_nowait())
-                except asyncio.QueueEmpty:
-                    break
-            self.metrics.batches.inc()
-            self.metrics.coalesced.observe(len(batch))
-            # group same-(op, modulus) requests into shared chunks
-            groups: dict[tuple, list[_Request]] = {}
-            for r in batch:
-                groups.setdefault((r.op, r.v), []).append(r)
-            for (op, v), members in groups.items():
-                try:
-                    await self._run_group(op, v, members)
-                except Exception as exc:      # never kill the worker
-                    for r in members:
-                        self._fail(r, exc)
+            with T.span("frontend.cycle"):
+                await self._cycle(req)
+
+    async def _cycle(self, req: _Request) -> None:
+        """One coalescing cycle: `req` and whatever else is queued by
+        the end of the coalescing window, run group by group."""
+        if self.policy.coalesce_window > 0:
+            await asyncio.sleep(self.policy.coalesce_window)
+        batch = [req]
+        while len(batch) < self.policy.max_batch_requests:
+            try:
+                batch.append(self._queue.get_nowait())
+            except asyncio.QueueEmpty:
+                break
+        self.metrics.coalesced.observe(len(batch))
+        # group same-(op, modulus) requests into shared chunks
+        groups: dict[tuple, list[_Request]] = {}
+        for r in batch:
+            groups.setdefault((r.op, r.v), []).append(r)
+        for (op, v), members in groups.items():
+            try:
+                await self._run_group(op, v, members)
+            except Exception as exc:      # never kill the worker
+                for r in members:
+                    self._fail(r, exc)
 
     async def _run_group(self, op: str, v, members: list[_Request]):
         # concatenate member columns; remember each member's segment
@@ -361,7 +375,8 @@ class AsyncFrontend:
         attempt = 0
         last_exc = None
         for _ in range(_MAX_CHUNK_ATTEMPTS):
-            if not self._live_members(segments, clo, chi):
+            live = self._live_members(segments, clo, chi)
+            if not live:
                 self.metrics.chunks_cancelled.inc()
                 return None
             eff = self.ladder.select(requested, bucket, m)
@@ -371,6 +386,8 @@ class AsyncFrontend:
             if eff != requested:
                 self.metrics.degraded.labels(
                     from_impl=requested, to_impl=eff).inc()
+            for r, _ in live:
+                r.wait.end()          # no-op after its first chunk
             try:
                 out = await loop.run_in_executor(
                     None, partial(self._call_service, op, v,
@@ -414,19 +431,20 @@ class AsyncFrontend:
     def _scatter(self, out, segments, clo, chi) -> None:
         """Deliver one chunk's result rows to the member requests and
         resolve any member that just completed."""
-        for r, glo in segments:
-            lo = max(glo, clo)
-            hi = min(glo + r.n, chi)
-            if lo >= hi or r.settled:
-                continue
-            for c in range(r.nout):
-                r.results[c][lo - glo:hi - glo] = \
-                    out[c][lo - clo:hi - clo]
-            r.done_items += hi - lo
-            self._items -= hi - lo
-            if r.done_items == r.n:
-                self._finish(r)
-        self._set_gauges()
+        with T.span("frontend.scatter"):
+            for r, glo in segments:
+                lo = max(glo, clo)
+                hi = min(glo + r.n, chi)
+                if lo >= hi or r.settled:
+                    continue
+                for c in range(r.nout):
+                    r.results[c][lo - glo:hi - glo] = \
+                        out[c][lo - clo:hi - clo]
+                r.done_items += hi - lo
+                self._items -= hi - lo
+                if r.done_items == r.n:
+                    self._finish(r)
+            self._set_gauges()
 
     # -- resolution -------------------------------------------------------
 
@@ -436,6 +454,7 @@ class AsyncFrontend:
         if req.settled:
             return False
         req.settled = True
+        req.wait.end()             # resolved before any chunk of it ran
         self._depth -= 1
         self._items -= req.n - req.done_items
         return True

@@ -16,7 +16,9 @@ launches incl. the scan-trip-weighted runtime count, XLA glue eqns,
 total eqns -- `utils/jaxpr_stats.trace_profile`) plus the
 `KernelPlan`; runtime counters cover requests, true-vs-padded rows,
 per-bucket latency, and the Barrett context cache
-(hits/misses/evictions).  `stats()` returns the runtime counters,
+(hits/misses/evictions); each host phase of a call (validate, pack,
+execute, unpack, and a cold key's precompute) is a `service.<phase>`
+span on the profiler's clock.  `stats()` returns the runtime counters,
 `snapshot()` the merged static + runtime profile that
 `obs/report.py` renders as a measured-vs-model table.
 """
@@ -33,7 +35,6 @@ import jax.numpy as jnp
 
 from repro.core import bigint as bi
 from repro.core import modarith as MA
-from repro.obs import telemetry as OBS
 from repro.utils import jaxpr_stats as JS
 from . import batching as BT
 from . import errors as E
@@ -128,9 +129,9 @@ class ModArithService:
             self._fire("precompute")
             self.ctx_misses += 1
             self._ctx_metric.labels(event="miss").inc()
-            with OBS.annotate("modexp_service/precompute"):
-                ctx = self._precompute(
-                    jnp.asarray(bi.from_int(v, self.m)))
+            with self.telemetry.phase("barrett", "precompute"):
+                ctx = jax.block_until_ready(self._precompute(
+                    jnp.asarray(bi.from_int(v, self.m))))
             self._ctxs[v] = ctx
             while len(self._ctxs) > self.max_cached:
                 self._ctxs.popitem(last=False)
@@ -178,8 +179,9 @@ class ModArithService:
                 raise ValueError(op)
             if self.capture_profiles:
                 zs = [jnp.zeros((bucket, w), jnp.uint32) for w in widths]
-                self.static_profiles.setdefault(bucket, {})[op] = \
-                    JS.trace_profile(f, self._zero_ctx(), *zs)
+                with self.telemetry.phase(op, "profile"):
+                    self.static_profiles.setdefault(bucket, {})[op] = \
+                        JS.trace_profile(f, self._zero_ctx(), *zs)
             return BT.sharded_jit(f, self.mesh, batched,
                                   n_args=1 + len(widths), n_out=1)
         return self._fns.get((op, bucket, eff), build)
@@ -215,11 +217,12 @@ class ModArithService:
         if len(columns) != len(schema):
             raise E.InvalidRequest(
                 f"{op} takes {len(schema)} columns, got {len(columns)}")
-        n = E.check_lengths(columns, names=[s[0] for s in schema])
-        for col, (name, lim, what) in zip(columns, schema):
-            E.check_operands(name, col, lim, what)
-        if v is not None:
-            self.check_modulus(v)
+        with self.telemetry.phase(op, "validate"):
+            n = E.check_lengths(columns, names=[s[0] for s in schema])
+            for col, (name, lim, what) in zip(columns, schema):
+                E.check_operands(name, col, lim, what)
+            if v is not None:
+                self.check_modulus(v)
         return n
 
     def _run(self, op: str, v: int, columns, widths, *,
@@ -237,16 +240,17 @@ class ModArithService:
         for lo, hi, bucket in self.batcher.plan(n):
             eff = BT.resolve_impl(impl or self.impl)
             self._fire("transfer", op=op, bucket=bucket)
-            arrs = [jnp.asarray(bi.batch_from_ints(
-                        BT.pad_ints(col[lo:hi], bucket, 0), w))
-                    for col, w in zip(columns, widths)]
+            with self.telemetry.phase(op, "pack"):
+                arrs = [jnp.asarray(bi.batch_from_ints(
+                            BT.pad_ints(col[lo:hi], bucket, 0), w))
+                        for col, w in zip(columns, widths)]
             fn = self._fn(op, bucket, impl)
             self.telemetry.record_rows(bucket, hi - lo)
-            with OBS.annotate(f"modexp_service/{op}/b{bucket}"), \
-                    self.telemetry.chunk_timer(op, bucket):
+            with self.telemetry.chunk_timer(op, bucket):
                 self._fire("execute", op=op, bucket=bucket, impl=eff)
                 res = np.asarray(fn(ctx, *arrs))
-            out += bi.batch_to_ints(res[:hi - lo])
+            with self.telemetry.phase(op, "unpack"):
+                out += bi.batch_to_ints(res[:hi - lo])
         return out
 
     # -- public entry points ----------------------------------------------

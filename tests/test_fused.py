@@ -213,6 +213,53 @@ def test_fused_launch_counts():
     assert ops_ref > ops_fused
 
 
+def _kernel_names(fn, *args) -> list[str]:
+    """The `name` of every pallas_call in fn's jaxpr, in launch order."""
+    jx = jax.make_jaxpr(fn)(*args)
+    return [e.params["name"]
+            for e in JS.iter_eqns(jx.jaxpr, into_kernels=False)
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("threshold,gen", [(None, ""), (1, "_grid")])
+def test_kernels_are_named_by_stage_in_launch_order(threshold, gen):
+    """Each launch carries its stage's name (the instruction's name in
+    the compiled program and in a device profile): Refine iteration i
+    at window w as refine_i<ii>_w<w>_powdiff / _update, then the
+    finalization; `_grid` marks the grid generation."""
+    m = 4
+    w = m + S.PAD                             # every window clips to w
+    u = jnp.zeros((3, m), jnp.uint32)
+    K.set_fused_grid_threshold(threshold)
+    try:
+        names = _kernel_names(
+            lambda a, b: S.divmod_batch(a, b, impl="pallas_fused"), u, u)
+        iters = S.refine_iters(m)
+        assert len(names) == 2 * iters + 1
+        assert names == [f"refine_i{i:02d}_w{w}_{stage}{gen}"
+                         for i in range(iters)
+                         for stage in ("powdiff", "update")] + \
+            [f"divmod_correct{gen}"]
+        ctx = MA.BarrettContext(v=jnp.zeros((m,), jnp.uint32),
+                                mu=jnp.zeros((MA.barrett_width(m),),
+                                             jnp.uint32),
+                                k=jnp.zeros((), jnp.int32))
+        x = jnp.zeros((3, 2 * m), jnp.uint32)
+        assert _kernel_names(lambda c, a: MA.reduce_shared(
+            c, a, impl="pallas_fused"), ctx, x) == [f"barrett{gen}"]
+    finally:
+        K.set_fused_grid_threshold(None)
+
+
+def test_products_are_named():
+    u = jnp.zeros((3, 8), jnp.uint32)
+    for impl, name in (("pallas", "bigmul"),
+                       ("pallas_batched", "bigmul_batched")):
+        names = _kernel_names(lambda a, b: jax.vmap(
+            lambda x, y: K.mul(x, y, 16, impl=impl))(a, b), u, u)
+        assert names and set(names) == {name}, (impl, names)
+
+
 def test_kernel_plan_records_fused_geometry():
     from repro.serving import batching as BT
     plan = BT.kernel_plan(16, 16, "pallas_fused")

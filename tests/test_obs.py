@@ -89,13 +89,49 @@ def test_registry_rejects_tracers():
         bad(jnp.float32(1.0))
 
 
-def test_timer_and_disabled_profiler_hooks():
-    with T.timer() as t:
-        pass
-    assert t.seconds >= 0.0
-    assert not T.profiling_enabled()
-    with T.scope("x"), T.annotate("y"):     # no-ops by default
-        pass
+def _host_events(trace_dir) -> dict:
+    """{name: [(start_ns, end_ns)]} of the host events of the one
+    profiler trace written under `trace_dir`."""
+    import glob
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    s = int(ev.start_ns)
+                    out.setdefault(ev.name, []).append(
+                        (s, s + int(ev.duration_ns)))
+    return out
+
+
+def test_span_observes_its_series_and_is_traced_by_name(tmp_path):
+    reg = T.Registry()
+    h = reg.histogram("lat", labelnames=("phase",))
+    other = reg.histogram("other")
+    with T.span("untraced", h.labels(phase="a")):
+        pass                                  # no profiler session
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with T.span("test.inner", (h.labels(phase="b"), other._default())):
+            pass
+        outer = T.span("test.outer", h.labels(phase="c"))
+        late = T.span("test.late")
+        outer.end()                           # ends before `late`
+        outer.end()                           # only the first end counts
+        late.end()
+    finally:
+        jax.profiler.stop_trace()
+    counts = {s.labels["phase"]: s.count for s in h.series()}
+    assert counts == {"a": 1, "b": 1, "c": 1}
+    assert other._default().count == 1
+    assert all(s.value >= 0.0 for s in h.series())
+    ev = _host_events(tmp_path)
+    assert len(ev["test.inner"]) == len(ev["test.outer"]) == 1
+    (o0, o1), = ev["test.outer"]
+    (l0, l1), = ev["test.late"]
+    assert o0 <= l0 <= o1 <= l1               # overlapping, out of order
+    assert "untraced" not in ev
 
 
 # ---------------------------------------------------------------------------
@@ -171,6 +207,53 @@ def test_division_service_pad_waste_exact():
     st = svc.stats()
     assert st["rows_true"] == 10 and st["rows_padded"] == 12
     assert st["pad_waste"] == pytest.approx(2 / 12)
+
+
+def _phase_counts(svc) -> dict:
+    phases = svc.telemetry.registry.get("phase_seconds")
+    return {f"{s.labels['op']}/{s.labels['phase']}": s.count
+            for s in phases.series()}
+
+
+def test_division_service_records_each_phase_once_per_chunk():
+    m = 4
+    svc = BigintDivisionService(m_limbs=m, impl="blocked",
+                                batch_buckets=(2, 4))
+    us = [B ** m - 1 - i for i in range(7)]
+    vs = [3 + i for i in range(7)]
+    qs, rs = svc.divide(us, vs)
+    assert list(zip(qs, rs)) == [divmod(u, v) for u, v in zip(us, vs)]
+    st = svc.stats()
+    counts = _phase_counts(svc)
+    chunks = 2                                # (0, 4, 4), (4, 7, 4)
+    assert counts == {"divmod/validate": 1, "divmod/profile": 1,
+                      "divmod/pack": chunks, "divmod/execute": chunks,
+                      "divmod/unpack": chunks}
+    assert sum(h["count"] for h in st["bucket_seconds"].values()) == \
+        counts["divmod/execute"]
+    execute, = [s for s in svc.telemetry.registry.get(
+        "phase_seconds").series() if s.labels["phase"] == "execute"]
+    assert st["bucket_seconds"]["divmod/b4"]["sum"] == \
+        pytest.approx(execute.value)             # one timing, two series
+
+
+def test_modexp_service_records_each_phase_once_per_chunk():
+    rnd = random.Random(5)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=1, impl="blocked",
+                          batch_buckets=(2,), capture_profiles=False)
+    v = rnd.randint(2, B ** m - 1)
+    a = [rnd.randint(0, B ** m - 1) for _ in range(3)]
+    e = [rnd.randint(0, B - 1) for _ in range(3)]
+    assert svc.modexp(a, e, v) == [pow(x, y, v) for x, y in zip(a, e)]
+    assert svc.modexp(a[:1], e[:1], v) == [pow(a[0], e[0], v)]
+    counts = _phase_counts(svc)
+    chunks = 2 + 1                            # (0, 2), (2, 3); then (0, 1)
+    assert counts == {"modexp/validate": 2, "barrett/precompute": 1,
+                      "modexp/pack": chunks, "modexp/execute": chunks,
+                      "modexp/unpack": chunks}
+    st = svc.stats()
+    assert st["bucket_seconds"]["modexp/b2"]["count"] == chunks
 
 
 def test_modarith_ctx_cache_counters_exact():

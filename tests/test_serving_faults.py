@@ -371,6 +371,36 @@ def test_frontend_already_expired_deadline_never_executes():
     assert svc.telemetry.stats()["rows_true"] == 0
 
 
+def test_frontend_records_one_queue_wait_per_admitted_request():
+    """Every admitted request records exactly one queue wait: at the
+    hand-off of its first chunk, or at its resolution when it expired
+    before any chunk ran.  Requests refused at admission record none."""
+    rnd = random.Random(35)
+    svc = _modarith()
+    v = rnd.randint(2, B ** 3 - 1)
+    xs = [rnd.randint(0, B ** 6 - 1) for _ in range(6)]
+
+    async def main():
+        async with AsyncFrontend(svc, policy=ServingPolicy(**FAST)) as fe:
+            outs = await asyncio.gather(
+                fe.submit("reduce", xs, v=v),           # two chunks
+                fe.submit("reduce", xs[:1], v=v),
+                fe.submit("reduce", xs[:2], v=v, timeout=0.0),
+                fe.submit("nope", [1], v=v),
+                return_exceptions=True)
+            assert outs[0] == [x % v for x in xs]
+            assert outs[1] == [xs[0] % v]
+            assert isinstance(outs[2], E.DeadlineExceeded)
+            assert isinstance(outs[3], E.InvalidRequest)
+            return fe.metrics
+    m = run(main())
+    waits = m.queue_wait.labels(op="reduce")
+    admitted = m.admitted.labels(op="reduce").value
+    assert admitted == 3 and waits.count == 3
+    assert waits.value >= 0.0
+    assert [s.labels for s in m.queue_wait.series()] == [{"op": "reduce"}]
+
+
 def test_frontend_overload_sheds_typed_rejections():
     rnd = random.Random(26)
     svc = _modarith()

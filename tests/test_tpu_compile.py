@@ -10,6 +10,7 @@ runs; exactness stays with the interpret-mode tests.
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 import jax
@@ -50,14 +51,22 @@ def _limbs(one_chip, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=one_chip)
 
 
-def _assert_kernel(fn, *args):
+def _assert_kernel(fn, *args) -> set[str]:
+    """Compile fn, require a Pallas kernel in it, and return the
+    kernels' instruction names without their vmap scope and number
+    (`%vmap_barrett_.3` -> `barrett`)."""
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return {re.sub(r"^vmap_(.*)_$", r"\1", m)
+            for m in re.findall(r"%([\w.-]+)\.\d+ = [^\n]*"
+                                r'custom_call_target="tpu_custom_call"',
+                                text)}
 
 
 def test_mul_pallas_batched_2e15(one_chip):
     x = _limbs(one_chip, 16, 2048)
-    _assert_kernel(lambda u, v: bigmul.mul_pallas_batched(u, v, 4096), x, x)
+    assert _assert_kernel(lambda u, v: bigmul.mul_pallas_batched(u, v, 4096),
+                          x, x) == {"bigmul_batched"}
 
 
 @pytest.mark.parametrize("bits,path", [(2 ** 12, "unrolled"),
@@ -67,8 +76,12 @@ def test_fused_divmod(one_chip, bits, path):
     w = m + S.PAD
     assert K.fused_path(2 * w, w, w, -(-2 * w // 128) * 128) == path
     x = _limbs(one_chip, 16, m)
-    _assert_kernel(lambda u, v: S.divmod_batch(u, v, impl="pallas_fused"),
-                   x, x)
+    names = _assert_kernel(
+        lambda u, v: S.divmod_batch(u, v, impl="pallas_fused"), x, x)
+    # the kernels keep their stage names as instruction names
+    assert f"divmod_correct{'_grid' if path == 'grid' else ''}" in names
+    assert "refine_i00_w32_powdiff" in names
+    assert len(names) == 2 * S.refine_iters(m) + 1
 
 
 def test_barrett_reduce_2048(one_chip):
@@ -76,6 +89,7 @@ def test_barrett_reduce_2048(one_chip):
     ctx = MA.BarrettContext(
         v=_limbs(one_chip, m), mu=_limbs(one_chip, MA.barrett_width(m)),
         k=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
-    _assert_kernel(
+    names = _assert_kernel(
         lambda c, x: MA.reduce_shared(c, x, impl="pallas_fused"),
         ctx, _limbs(one_chip, 16, 2 * m))
+    assert names == {"barrett"}
