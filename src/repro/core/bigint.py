@@ -16,10 +16,15 @@ paper's 64-bit-digit CUDA representation:
     matmuls (see kernels/), replacing CUDA per-thread digit loops with
     MXU/VPU-friendly dense products.
 
-Host-side conversion helpers here are NumPy-only (not traced).
+Host-side conversion helpers here are NumPy-only (not traced): a batch
+of Python ints becomes one little-endian byte buffer (`int.to_bytes`)
+viewed as uint16 limbs, and back through `int.from_bytes` per row, so
+conversion is linear in the operand width.
 """
 
 from __future__ import annotations
+
+import operator
 
 import numpy as np
 import jax.numpy as jnp
@@ -37,34 +42,53 @@ def width_for_bits(bits: int) -> int:
 
 def from_int(x: int, m: int) -> np.ndarray:
     """Python int -> little-endian limb vector of length m (host)."""
-    if x < 0:
-        raise ValueError("unsigned representation only")
-    out = np.zeros(m, dtype=np.uint32)
-    i = 0
-    while x:
-        if i >= m:
-            raise OverflowError("value does not fit in m limbs")
-        out[i] = x & MASK
-        x >>= LOG_BASE
-        i += 1
-    return out
+    return batch_from_ints([x], m)[0]
 
 
 def to_int(limbs) -> int:
     """Limb vector -> Python int (host)."""
-    limbs = np.asarray(limbs, dtype=np.uint64)
-    x = 0
-    for d in limbs[::-1]:
-        x = (x << LOG_BASE) | int(d)
-    return x
+    return batch_to_ints(np.asarray(limbs).reshape(1, -1))[0]
+
+
+def _limb_bytes(x, m: int) -> bytes:
+    x = operator.index(x)
+    if x < 0:
+        raise ValueError("unsigned representation only")
+    try:
+        return x.to_bytes(2 * m, "little")
+    except OverflowError:
+        raise OverflowError("value does not fit in m limbs") from None
 
 
 def batch_from_ints(xs, m: int) -> np.ndarray:
-    return np.stack([from_int(x, m) for x in xs])
+    """Python ints -> (n, m) uint32 limb array (host), one byte pass per row."""
+    xs = list(xs)
+    buf = b"".join(_limb_bytes(x, m) for x in xs)
+    return (np.frombuffer(buf, dtype="<u2").reshape(len(xs), m)
+            .astype(np.uint32))
 
 
 def batch_to_ints(arr) -> list[int]:
-    return [to_int(row) for row in np.asarray(arr)]
+    """(n, m) limb array -> n Python ints (host), one byte pass per row.
+
+    Every limb the kernels produce is < B.  A hand-built row with larger
+    limbs still reads as the exact sum of d_i * B^i, one 16-bit plane at
+    a time."""
+    a = np.asarray(arr)
+    if a.dtype.kind == "i" and a.size and a.min() < 0:
+        raise ValueError("unsigned representation only")
+    if not a.size or a.max() <= MASK:
+        return [int.from_bytes(row.tobytes(), "little")
+                for row in a.astype("<u2")]
+    a = a.astype(np.uint64)
+    out = [0] * len(a)
+    shift = 0
+    while a.any():
+        out = [x + (p << shift)
+               for x, p in zip(out, batch_to_ints(a & MASK))]
+        a >>= LOG_BASE
+        shift += LOG_BASE
+    return out
 
 
 def random_ints(rng: np.random.Generator, n: int, digits: int,

@@ -81,6 +81,80 @@ def test_cost_model_bounds():
 
 
 # ---------------------------------------------------------------------------
+# host int <-> limb conversion vs the per-limb shift loops
+# ---------------------------------------------------------------------------
+
+def _shift_from_int(x, m):
+    """Reference: one 16-bit limb per step (the original loop)."""
+    if x < 0:
+        raise ValueError("unsigned representation only")
+    out = np.zeros(m, dtype=np.uint32)
+    i = 0
+    while x:
+        if i >= m:
+            raise OverflowError("value does not fit in m limbs")
+        out[i] = x & bi.MASK
+        x >>= bi.LOG_BASE
+        i += 1
+    return out
+
+
+def _shift_to_int(limbs):
+    x = 0
+    for d in np.asarray(limbs, dtype=np.uint64)[::-1]:
+        x = (x << bi.LOG_BASE) | int(d)
+    return x
+
+
+@pytest.mark.parametrize("m", [1, 2, 128, 2048, 16384])
+def test_int_limb_roundtrip_matches_shift_loops(m):
+    rnd = random.Random(m)
+    xs = [0, 1, B ** m - 1,
+          rnd.randrange(B ** (m // 2 + 1)),        # leading zero limbs
+          rnd.randrange(B ** m), rnd.randrange(B ** m)]
+    ref = np.stack([_shift_from_int(x, m) for x in xs])
+
+    arr = bi.batch_from_ints(xs, m)
+    assert arr.dtype == np.uint32 and arr.shape == (len(xs), m)
+    np.testing.assert_array_equal(arr, ref)
+    assert bi.batch_to_ints(arr) == xs == [_shift_to_int(r) for r in ref]
+    for x, row in zip(xs, ref):
+        one = bi.from_int(x, m)
+        assert one.dtype == np.uint32 and one.shape == (m,)
+        np.testing.assert_array_equal(one, row)
+        assert bi.to_int(row) == x
+    assert bi.to_int(jnp.asarray(ref[-1])) == xs[-1]
+    assert bi.to_int([int(d) for d in ref[-1]]) == xs[-1]
+
+    empty = bi.batch_from_ints([], m)
+    assert empty.dtype == np.uint32 and empty.shape == (0, m)
+    assert bi.batch_to_ints(empty) == []
+
+    with pytest.raises(ValueError, match="^unsigned representation only$"):
+        bi.from_int(-1, m)
+    with pytest.raises(ValueError, match="^unsigned representation only$"):
+        bi.batch_from_ints([1, -rnd.randrange(1, B ** m)], m)
+    with pytest.raises(OverflowError,
+                       match="^value does not fit in m limbs$"):
+        bi.from_int(B ** m, m)
+    with pytest.raises(OverflowError,
+                       match="^value does not fit in m limbs$"):
+        bi.batch_from_ints([0, B ** m + rnd.randrange(B ** m)], m)
+    with pytest.raises(ValueError, match="^unsigned representation only$"):
+        bi.to_int([-1] + [0] * (m - 1))
+
+    # hand-built limbs >= B read as the exact sum of d_i * B^i
+    wide = np.array([rnd.choice([B, B + 1, 2 ** 32 - 1, rnd.randrange(B)])
+                     for _ in range(m)], dtype=np.uint32)
+    lo, hi = wide & bi.MASK, wide >> bi.LOG_BASE       # d_i = lo_i + hi_i B
+    exact = _shift_to_int(lo) + (_shift_to_int(hi) << bi.LOG_BASE)
+    assert bi.to_int(wide) == exact
+    assert bi.batch_to_ints(np.stack([wide, ref[-1]])) == [exact, xs[-1]]
+    big = np.array([2 ** 64 - 1] + [0] * (m - 1), dtype=np.uint64)
+    assert bi.to_int(big) == 2 ** 64 - 1
+
+
+# ---------------------------------------------------------------------------
 # JAX implementation vs oracle
 # ---------------------------------------------------------------------------
 

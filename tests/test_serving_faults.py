@@ -463,6 +463,45 @@ def test_frontend_coalesces_concurrent_requests_into_one_bucket():
     assert st["rows_padded"] <= 8, st
 
 
+@pytest.mark.parametrize("op", ["divmod", "modexp"])
+def test_frontend_exact_on_full_and_padded_buckets(op):
+    """Rows that fill bucket 4, rows that pad it, and a request split
+    into a full chunk and a padded one come back as Python's
+    divmod / pow, edge operands (0, 1, B^m - 1) included."""
+    rnd = random.Random(36)
+    m, top = 4, B ** 4 - 1
+    xs = [0, 1, top, top - 1] + [rnd.randint(0, top) for _ in range(9)]
+    if op == "divmod":
+        svc = BigintDivisionService(m_limbs=m, impl="blocked",
+                                    batch_buckets=(4,),
+                                    capture_profiles=False)
+        ys = [1, top, 2, B] + [rnd.randint(1, B ** rnd.randint(1, m) - 1)
+                               for _ in range(9)]
+        want = [(x // y, x % y) for x, y in zip(xs, ys)]
+        cols, kw = (xs, ys), {}
+    else:
+        svc = ModArithService(m_limbs=m, e_limbs=2, impl="blocked",
+                              batch_buckets=(4,), capture_profiles=False)
+        n = rnd.randint(B ** (m - 1), top) | 1
+        xs = [x % n for x in xs]
+        es = [0, 1, B ** 2 - 1, 2] + [rnd.randint(0, B ** 2 - 1)
+                                      for _ in range(9)]
+        want = [pow(x, e, n) for x, e in zip(xs, es)]
+        cols, kw = (xs, es), {"v": n}
+
+    def answers(out):
+        return list(zip(*out)) if op == "divmod" else out
+
+    async def main():
+        async with AsyncFrontend(svc, policy=ServingPolicy(**FAST)) as fe:
+            for lo, hi in [(0, 4), (4, 7), (7, 13)]:   # full, pad, both
+                got = await fe.submit(op, *(c[lo:hi] for c in cols), **kw)
+                assert answers(got) == want[lo:hi], (lo, hi)
+    run(main())
+    st = svc.telemetry.stats()
+    assert st["rows_true"] == 13 and st["rows_padded"] == 16, st
+
+
 def test_frontend_stop_without_drain_cancels_queued():
     rnd = random.Random(29)
     svc = _modarith()
