@@ -12,9 +12,12 @@ window and the line carries the per-layer metrics; with `--trace 0` it
 carries the end-to-end ones.
 
 Everything is found by name: the cell in `BENCHMARK.json`, its
-configuration in `configs/<config>.json`, its operation in
-`ops/<op>.py`, its mix in `traffic/<traffic>.json` and each per-layer
-metric's reader in `metrics/<name>.py`.
+configuration in `configs/<config>.json` (and its tiny copy for the
+CPU tests in `tests/data/tiny-<config>.json`), its operation in
+`ops/<op>.py` (the service method spanned is the one the frontend
+calls for it), its mix in `traffic/<traffic>.json` and each per-layer
+metric's reader in `metrics/<name>.py`.  A new configuration enters as
+new files and entries alone.
 """
 
 from __future__ import annotations
@@ -91,28 +94,28 @@ class Cell:
 
 class Spanned:
     """The service as the frontend sees it, with a host span and a
-    record around each call (the frontend makes one call per bucket)."""
+    record around each call of the endpoint `method` (the frontend
+    makes one call per bucket); every other attribute is the
+    service's own."""
 
-    def __init__(self, service):
+    def __init__(self, service, method: str):
+        import jax
         self._service = service
         self.calls: list[tuple[float, float, int]] = []
+        meth = getattr(service, method)
+        annotate = jax.profiler.TraceAnnotation
+
+        def timed(*args, **kw):
+            rows = len(args[0])
+            t0 = time.perf_counter()
+            with annotate(f"{CALL_SPAN} rows={rows}"):
+                out = meth(*args, **kw)
+            self.calls.append((t0, time.perf_counter(), rows))
+            return out
+        setattr(self, method, timed)
 
     def __getattr__(self, name):
         return getattr(self._service, name)
-
-    def _timed(self, meth, rows, *args, **kw):
-        import jax
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(f"{CALL_SPAN} rows={rows}"):
-            out = meth(*args, **kw)
-        self.calls.append((t0, time.perf_counter(), rows))
-        return out
-
-    def divide(self, us, vs, **kw):
-        return self._timed(self._service.divide, len(us), us, vs, **kw)
-
-    def modexp(self, a, e, v, **kw):
-        return self._timed(self._service.modexp, len(a), a, e, v, **kw)
 
 
 @dataclass
@@ -139,6 +142,13 @@ class Run:
     ctx_misses: int | None = None
     trace: object = None            # tracereduce.Reading of the traced part
     peaks: dict | None = None
+
+
+def quantity(name: str) -> str:
+    """What an end-to-end metric measures: its name, less a last
+    `.<part>` that splits one quantity into metrics bounded for
+    different cells (`latency_p95_ms.single` is `latency_p95_ms`)."""
+    return name.rsplit(".", 1)[0]
 
 
 def percentile(values: list[float], p: float) -> float:
@@ -316,7 +326,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     log(f"[bench] {name}: {len(devs)} x {dev.device_kind} "
         f"({dev.platform})")
     compiles = CompileCounter(jax)
-    from repro.serving.frontend import AsyncFrontend
+    from repro.serving.frontend import _OPS, AsyncFrontend
     import gen
 
     service = op.build_service(cfg)
@@ -324,7 +334,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         service = op.Control(service)
     if wrap is not None:
         service = wrap(service)
-    spanned = Spanned(service)
+    spanned = Spanned(service, _OPS[op.OP][0])
     stream = gen.Stream(op, cfg, mix, seed)
 
     # -- set-up: the cache state, every reachable bucket, the frontend
@@ -454,7 +464,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
         for m in cell.end_to_end:
-            metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
+            metrics[m["name"]] = {"value": e2e[quantity(m["name"])],
+                                  "unit": m["unit"]}
     for k, v in e2e.items():
         log(f"[bench] {k} {v}")
     compared = {

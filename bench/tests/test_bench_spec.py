@@ -99,8 +99,31 @@ def test_configs_state_their_cuts():
 
 
 def test_per_layer_metrics_move_a_reported_metric():
+    import harness
     e2e = {m["name"] for m in SPEC["end_to_end"]}
     cells = {w["name"] for w in SPEC["workloads"]}
     for m in SPEC["per_layer"]:
         assert m["moves"] in e2e
         assert set(m.get("workloads", cells)) <= cells
+    for w in SPEC["workloads"]:
+        cell = harness.Cell.load(w["name"])
+        reported = {m["name"] for m in cell.end_to_end}
+        assert all(m["moves"] in reported for m in cell.per_layer)
+
+
+def test_each_cell_reports_each_quantity_once():
+    """A quantity split into metrics bounded for different cells
+    (`latency_p95_ms.single` reads `latency_p95_ms`) reaches each cell
+    under one name."""
+    import harness
+    cells = {w["name"] for w in SPEC["workloads"]}
+    for m in SPEC["end_to_end"]:
+        assert set(m.get("workloads", cells)) <= cells
+    assert harness.quantity("latency_p95_ms.single") == "latency_p95_ms"
+    assert harness.quantity("ops_per_s") == "ops_per_s"
+    for w in SPEC["workloads"]:
+        names = [m["name"] for m in harness.Cell.load(w["name"]).end_to_end]
+        quantities = [harness.quantity(n) for n in names]
+        assert len(quantities) == len(set(quantities)), names
+        assert set(quantities) == {"ops_per_s", "latency_p50_ms",
+                                   "latency_p95_ms", "setup_s"}

@@ -1,6 +1,8 @@
 """The reduction from a trace to busy time, idle share, kernel time and
-count, and the breakdown, on traces built by hand."""
+count, and the breakdown, on traces built by hand; and the harness's
+span and record around the endpoint each service call goes through."""
 
+import contextlib
 import sys
 from pathlib import Path
 from types import SimpleNamespace as NS
@@ -15,7 +17,8 @@ MS = 1_000_000
 
 
 def reading():
-    # two calls of 4 rows; kernels k overlap glue g in the first
+    # two calls of 4 rows, one program run each; kernels k overlap glue
+    # g in the first; a stray program runs outside the calls
     ops = [("k1", 0 * MS, 4 * MS, True), ("g1", 3 * MS, 6 * MS, False),
            ("k2", 10 * MS, 12 * MS, True), ("g2", 12 * MS, 13 * MS, False),
            ("stray", 20 * MS, 21 * MS, False)]
@@ -23,6 +26,8 @@ def reading():
             ("bench.call rows=4", 9 * MS, 14 * MS),
             ("convert", 6 * MS, 9 * MS), ("loop", 0, 25 * MS)]
     return T.Reading(ops=ops, host=host,
+                     programs=[(0, 6 * MS), (10 * MS, 13 * MS),
+                               (20 * MS, 21 * MS)],
                      spans=[(-1 * MS, 7 * MS, 4), (9 * MS, 14 * MS, 4)])
 
 
@@ -40,6 +45,24 @@ def test_kernel_time_and_count_inside_the_call_spans():
     assert r.kernel_count() == 2
     assert abs(r.kernel_time_s() - 0.006) < 1e-12
     assert r.span_rows() == 8
+
+
+def test_ops_are_placed_by_their_program_run_across_the_clocks():
+    # the device's clock reads 0.4 ms early against the host's, as on a
+    # v5e: the first call's program begins before its span does, and the
+    # program of a call whose span is not whole overlaps the last span
+    us = MS // 1000
+    ops = [("k_small", 600 * us, 900 * us, True),
+           ("g", 900 * us, 1000 * us, False),
+           ("k_big", 1000 * us, 10600 * us, True),
+           ("k_next", 14800 * us, 16000 * us, True)]
+    r = T.Reading(ops=ops,
+                  programs=[(-9000 * us, 700 * us), (600 * us, 10600 * us),
+                            (14800 * us, 24800 * us)],
+                  spans=[(1000 * us, 15000 * us, 1)])
+    assert r.calls_programs() == [(600 * us, 10600 * us)]
+    assert r.kernel_count() == 2
+    assert abs(r.busy_s() - 0.010) < 1e-12
 
 
 def test_breakdown_orders_ops_and_gaps_and_labels_gaps():
@@ -118,6 +141,7 @@ def test_from_profile_sorts_planes_and_finds_kernels():
                      ("vmap__.44", 10, 60, True),
                      ("vmap__.44", 70, 75, True)]
     assert r.spans == [(-5, 115, 3)]
+    assert r.programs == [(0, 100)]
     assert len(r.host) == 2
     assert r.kernel_count() == 2
     assert r.window_s() == 120e-9
@@ -131,3 +155,37 @@ def test_tracer_counts_the_calls_that_lie_whole_in_the_trace():
     assert tr.whole_calls(2.5, 3.0) == 0
     calls.append((2.6, 2.9, 8))                  # appended while tracing
     assert tr.whole_calls(2.5, 3.0) == 1
+
+
+def test_spanned_records_and_spans_the_named_endpoint(monkeypatch):
+    import jax
+    names = []
+
+    @contextlib.contextmanager
+    def annotation(name):
+        names.append(name)
+        yield
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
+
+    class Service:
+        m_limbs = 4
+
+        def reduce(self, xs, v, *, impl=None):
+            return [x % v for x in xs]
+
+        def modexp(self, a, e, v, *, impl=None):
+            return [pow(x, y, v) for x, y in zip(a, e)]
+
+    svc = Service()
+    s = harness.Spanned(svc, "reduce")
+    assert s.reduce([10, 11, 12], 7, impl="blocked") == [3, 4, 5]
+    assert names == [f"{harness.CALL_SPAN} rows=3"]
+    assert len(s.calls) == 1
+    t0, t1, rows = s.calls[0]
+    assert t0 <= t1 and rows == 3
+    # every other attribute is the service's own, called without a span
+    assert s.m_limbs == 4
+    assert s.modexp([2], [5], 7) == [4]
+    assert s.modexp.__self__ is svc
+    assert len(s.calls) == 1 and len(names) == 1

@@ -14,9 +14,7 @@ import harness  # noqa: E402
 
 # mixes of the cells kept for later (PERF.md, Open questions), built
 # here: the generator serves them, no cell runs them yet
-MIXES = {"div2p15-single": ("table1-div-2p15",
-                            gen.Mix("closed", 4, 1, 0, 0.0)),
-         "modexp2048-manykeys": ("rsa2048-modexp",
+MIXES = {"modexp2048-manykeys": ("rsa2048-modexp",
                                  gen.Mix("closed", 16, 1, 256, 1.0))}
 
 

@@ -1,20 +1,26 @@
 """Reduction of a profiler trace to what the per-layer metrics read.
 
-A trace is reduced to three lists of plain tuples, so that the
+A trace is reduced to four lists of plain tuples, so that the
 arithmetic below can be checked on a trace built by hand:
 
-    ops    (name, start_ns, end_ns, is_kernel)  device operations
-    host   (name, start_ns, end_ns)             host events
-    spans  (start_ns, end_ns, rows)             the benchmark's
-                                                "bench.call rows=<n>"
-                                                spans, one per service call
+    ops       (name, start_ns, end_ns, is_kernel)  device operations
+    programs  (start_ns, end_ns)                   device program runs
+                                                   ("XLA Modules")
+    host      (name, start_ns, end_ns)             host events
+    spans     (start_ns, end_ns, rows)             the benchmark's
+                                                   "bench.call rows=<n>"
+                                                   spans, one per
+                                                   service call
 
 Busy time is the union of the intervals of the device operations that
 ran inside the benchmark's whole calls, over the window those calls
-span.  A Pallas
-kernel is recognised by what XLA records for the custom call that
-carries it (KERNEL_RULE), not by the kernel's function name, so naming
-the kernels does not move what the metrics read.
+span.  An operation is placed in a call by the program run that holds
+it, and a program run in the call whose span holds most of it: the
+device's clock and the host's can disagree by about a millisecond,
+more than a one-row call leaves between its span's start and its first
+operation.  A Pallas kernel is recognised by what XLA records for the
+custom call that carries it (KERNEL_RULE), not by the kernel's function
+name, so naming the kernels does not move what the metrics read.
 """
 
 from __future__ import annotations
@@ -26,6 +32,7 @@ from dataclasses import dataclass, field
 
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+PROGRAMS_LINE = "XLA Modules"
 SPAN_PREFIX = "bench.call rows="
 KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
 KERNEL_RULE = ("a device op on the XLA Ops line whose HLO text (the "
@@ -97,21 +104,38 @@ class Reading:
     device work only inside calls, and the profiler's own start and
     stop, which hold up the host, fall outside it)."""
     ops: list = field(default_factory=list)
+    programs: list = field(default_factory=list)
     host: list = field(default_factory=list)
     spans: list = field(default_factory=list)
     _inside: list | None = field(default=None, repr=False)
 
+    def calls_programs(self) -> list[tuple[int, int]]:
+        """The program runs of the whole calls: each run more than half
+        of which lies inside one call's span (the frontend makes one
+        call at a time, so spans do not overlap)."""
+        spans = sorted(self.spans)
+        starts = [a for a, _, _ in spans]
+        kept = []
+        for p0, p1 in sorted(self.programs):
+            near = spans[max(bisect.bisect_right(starts, p0) - 1, 0):
+                         bisect.bisect_left(starts, p1)]
+            held = max((min(b, p1) - max(a, p0) for a, b, _ in near),
+                       default=0)
+            if 2 * held > p1 - p0:
+                kept.append((p0, p1))
+        return kept
+
     def in_spans(self):
-        """Device operations that lie inside a service call's span (the
-        frontend makes one call at a time, so spans do not overlap)."""
+        """Device operations of the whole calls: those inside the calls'
+        program runs (`calls_programs`)."""
         if self._inside is None:
-            spans = sorted(self.spans)
-            starts = [a for a, _, _ in spans]
+            programs = self.calls_programs()
+            starts = [a for a, _ in programs]
             self._inside = []
             for op in self.ops:
                 _, s, e, _ = op
                 k = bisect.bisect_right(starts, s) - 1
-                if k >= 0 and e <= spans[k][1]:
+                if k >= 0 and e <= programs[k][1]:
                     self._inside.append(op)
         return self._inside
 
@@ -171,6 +195,10 @@ def from_profile(pd) -> Reading:
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PLANE):
             for line in plane.lines:
+                if line.name == PROGRAMS_LINE:
+                    r.programs += [(int(ev.start_ns),
+                                    int(ev.start_ns + ev.duration_ns))
+                                   for ev in line.events]
                 if line.name != OPS_LINE:
                     continue
                 append = r.ops.append
