@@ -176,13 +176,13 @@ def modexp_phase(rng: random.Random) -> None:
     svc = ModArithService(MODEXP_LIMBS, batch_buckets=(MODEXP_BUCKET,))
     v, reqs = modexp_requests(rng)
     t0 = time.perf_counter()
-    ctx = svc.context(v)
-    jax.block_until_ready(ctx)
+    jax.block_until_ready(svc.context(v))
     pre_s = time.perf_counter() - t0
     spec = jax.ShapeDtypeStruct((MODEXP_BUCKET, MODEXP_LIMBS), jnp.uint32)
+    slots = jax.ShapeDtypeStruct((MODEXP_BUCKET,), jnp.int32)
     compile_s, _ = compile_bucket(
-        name, lambda: svc._fn("modexp", MODEXP_BUCKET).lower(ctx, spec,
-                                                             spec))
+        name, lambda: svc._fn("modexp", MODEXP_BUCKET).lower(
+            svc._slab, slots, spec, spec))
     times = []
     for _ in range(2):
         out, dt, fe = asyncio.run(serve(svc, "modexp", reqs, v=v))

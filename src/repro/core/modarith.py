@@ -41,9 +41,11 @@ JAX adaptation notes (mirroring shinv.py):
 "pallas_batched" | "pallas_fused"), `windowed` the size-bucketed
 Newton refinement -- both threaded through exactly like
 `shinv.divmod_batch`.  With "pallas_batched" `K.mul` is batch-aware:
-the vmapped `reduce_shared` / `modmul_shared` / `modexp_shared` hot
-paths execute each truncated multiplication as one natively batched
-kernel launch across the whole request batch.  With "pallas_fused"
+the vmapped per-row-context paths (`reduce_rows` / `modmul_rows` /
+`modexp_rows`, which the serving layer runs, each row's context
+gathered from a `ContextSlab`) and `reduce_shared` execute each
+truncated multiplication as one natively batched kernel launch
+across the whole request batch.  With "pallas_fused"
 (the TPU default) the whole `barrett_reduce` core -- both truncated
 products AND the conditional subtracts -- is ONE batched launch
 (`K.fused_barrett`, kernels/fused.py); that single-launch contract
@@ -67,6 +69,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .bigint import LOG_BASE, DTYPE, one_hot_pow
 from . import arith as A
@@ -226,36 +229,71 @@ def modexp_batch(a: jax.Array, e: jax.Array, v: jax.Array,
     return jax.vmap(one)(a, e, v)
 
 
-# Shared-modulus variants: ctx computed once (cached by the serving
-# layer), broadcast across the batch -- the amortized hot path.
+# Per-row contexts: a slab of cached contexts on the device, one per
+# slot; each row of a batch gathers its own by slot index inside the
+# program.  The serving layer's hot path (serving/modexp_service.py):
+# one key is the case where every row names the same slot.
+
+class ContextSlab(NamedTuple):
+    """Barrett contexts of up to C moduli, one per slot, stacked like a
+    batched `BarrettContext`."""
+    v: jax.Array     # (C, m)
+    mu: jax.Array    # (C, barrett_width(m))
+    k: jax.Array     # (C,) int32
+
+
+def empty_slab(slots: int, m: int) -> ContextSlab:
+    # host zeros, copied in: no device program to compile
+    w = barrett_width(m)
+    return ContextSlab(v=jnp.asarray(np.zeros((slots, m), DTYPE)),
+                       mu=jnp.asarray(np.zeros((slots, w), _U)),
+                       k=jnp.asarray(np.zeros((slots,), _I)))
+
+
+def precompute_into(slab: ContextSlab, v: jax.Array, slots: jax.Array,
+                    *, impl: str | None = None,
+                    windowed: bool = True) -> ContextSlab:
+    """One batched precompute of the moduli v (n, m), each written into
+    its slot (slots: (n,) int32).  Rows that name one slot must carry
+    one modulus (padding repeats a row)."""
+    ctx = jax.vmap(partial(barrett_precompute, impl=impl,
+                           windowed=windowed))(v)
+    return ContextSlab(*(s.at[slots].set(c) for s, c in zip(slab, ctx)))
+
+
+def rows_context(slab: ContextSlab, slots: jax.Array) -> BarrettContext:
+    """The batched context of rows whose moduli sit in `slots`."""
+    return BarrettContext(*(s[slots] for s in slab))
+
+
+def reduce_rows(slab: ContextSlab, slots: jax.Array, x: jax.Array,
+                impl: str | None = None):
+    """[x_i mod v_i], row i's modulus in slot slots[i]."""
+    return jax.vmap(lambda c, xi: barrett_reduce(c, xi, impl=impl))(
+        rows_context(slab, slots), x)
+
+
+def modmul_rows(slab: ContextSlab, slots: jax.Array, a: jax.Array,
+                b: jax.Array, impl: str | None = None):
+    return jax.vmap(lambda c, ai, bi_: modmul(c, ai, bi_, impl=impl))(
+        rows_context(slab, slots), a, b)
+
+
+def modexp_rows(slab: ContextSlab, slots: jax.Array, a: jax.Array,
+                e: jax.Array, impl: str | None = None,
+                window_bits: int = 4):
+    return jax.vmap(lambda c, ai, ei: modexp(c, ai, ei, impl=impl,
+                                             window_bits=window_bits))(
+        rows_context(slab, slots), a, e)
+
+
+# One context broadcast across the batch (kernel tests and benchmarks).
 
 def reduce_shared(ctx: BarrettContext, x: jax.Array,
                   impl: str | None = None):
     return jax.vmap(lambda xi: barrett_reduce(ctx, xi, impl=impl))(x)
 
 
-def modmul_shared(ctx: BarrettContext, a: jax.Array, b: jax.Array,
-                  impl: str | None = None):
-    return jax.vmap(lambda ai, bi_: modmul(ctx, ai, bi_, impl=impl))(a, b)
-
-
-def modexp_shared(ctx: BarrettContext, a: jax.Array, e: jax.Array,
-                  impl: str | None = None, window_bits: int = 4):
-    return jax.vmap(lambda ai, ei: modexp(ctx, ai, ei, impl=impl,
-                                          window_bits=window_bits))(a, e)
-
-
 @partial(jax.jit, static_argnames=("impl",))
 def reduce_shared_batch(ctx, x, impl: str | None = None):
     return reduce_shared(ctx, x, impl=impl)
-
-
-@partial(jax.jit, static_argnames=("impl",))
-def modmul_shared_batch(ctx, a, b, impl: str | None = None):
-    return modmul_shared(ctx, a, b, impl=impl)
-
-
-@partial(jax.jit, static_argnames=("impl", "window_bits"))
-def modexp_shared_batch(ctx, a, e, impl: str | None = None,
-                        window_bits: int = 4):
-    return modexp_shared(ctx, a, e, impl=impl, window_bits=window_bits)

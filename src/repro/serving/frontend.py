@@ -12,10 +12,17 @@ millions-of-users target needs:
               exceeds policy -- BEFORE anything is enqueued, so a
               rejected request costs nothing.
   coalescing  a single consumer drains arrivals each cycle and merges
-              same-(op, modulus) requests into shared bucket chunks
+              same-op requests into shared bucket chunks
               (`Batcher.plan` over the concatenated rows), so k small
               concurrent requests fill one padded executable instead
-              of k mostly-padding launches.
+              of k mostly-padding launches.  Modular requests over
+              different moduli share chunks too: each chunk carries
+              one modulus per row, sliced with its other columns
+              through retries, degradation, deadlines and scatter.
+              (A service that keeps no context slab -- its `stats()`
+              reports no `ctx_cache.slots`, as a stand-in serving a
+              reference one modulus a call -- is handed one modulus a
+              chunk: its requests group by (op, modulus).)
   deadlines   per-request, propagated through chunk execution:
               expiry is checked cooperatively at every chunk boundary
               (a running kernel cannot be preempted), not-yet-
@@ -181,6 +188,10 @@ class AsyncFrontend:
         self._rng = random.Random(self.policy.retry_seed)
         self._depth = 0           # admitted, not yet resolved
         self._items = 0           # admitted rows, not yet computed
+        # modular chunks carry one modulus per row where the service
+        # keeps a context slab (module docstring, "coalescing")
+        self._row_moduli = "slots" in (
+            service.stats().get("ctx_cache") or {})
 
     # -- lifecycle --------------------------------------------------------
 
@@ -301,10 +312,12 @@ class AsyncFrontend:
             except asyncio.QueueEmpty:
                 break
         self.metrics.coalesced.observe(len(batch))
-        # group same-(op, modulus) requests into shared chunks
+        # group same-op requests into shared chunks (and same-modulus,
+        # for a service that takes one modulus a call)
         groups: dict[tuple, list[_Request]] = {}
         for r in batch:
-            groups.setdefault((r.op, r.v), []).append(r)
+            v = None if self._row_moduli else r.v
+            groups.setdefault((r.op, v), []).append(r)
         for (op, v), members in groups.items():
             try:
                 await self._run_group(op, v, members)
@@ -313,24 +326,30 @@ class AsyncFrontend:
                     self._fail(r, exc)
 
     async def _run_group(self, op: str, v, members: list[_Request]):
-        # concatenate member columns; remember each member's segment
+        # concatenate member columns (and, for modular ops over several
+        # moduli, a column of each row's modulus); remember each
+        # member's segment
         ncols = len(members[0].cols)
         cols = [[] for _ in range(ncols)]
+        moduli = []
         segments = []                          # (req, global lo)
         total = 0
         for r in members:
             segments.append((r, total))
             for c in range(ncols):
                 cols[c].extend(r.cols[c])
+            moduli.extend([r.v] * r.n)
             total += r.n
+        per_row = v is None and op != "divmod"
         for clo, chi, bucket in self.service.batcher.plan(total):
             live = self._live_members(segments, clo, chi)
             if not live:
                 self.metrics.chunks_cancelled.inc()
                 continue
             chunk_cols = [c[clo:chi] for c in cols]
+            chunk_v = moduli[clo:chi] if per_row else v
             try:
-                out = await self._execute_chunk(op, v, chunk_cols,
+                out = await self._execute_chunk(op, chunk_v, chunk_cols,
                                                 bucket, segments,
                                                 clo, chi)
             except Exception as exc:
@@ -417,8 +436,8 @@ class AsyncFrontend:
             E.ServingError("chunk attempt budget exhausted")
 
     def _call_service(self, op, v, chunk_cols, impl):
-        """Runs on the worker thread.  Returns a tuple of result
-        columns, each len(chunk)."""
+        """Runs on the worker thread; `v` is the chunk's modulus or one
+        per row.  Returns a tuple of result columns, each len(chunk)."""
         meth = getattr(self.service, _OPS[op][0], None)
         if meth is None:
             raise E.InvalidRequest(

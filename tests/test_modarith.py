@@ -2,14 +2,17 @@
 vs Python ints at multiple precisions, edge cases, impl dispatch."""
 
 import random
+from functools import partial
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from _hyp import given, settings, st
 from repro.core import bigint as bi
 from repro.core import modarith as MA
+from repro.core import pyref
 from repro.core import shinv as S
 
 B = bi.BASE
@@ -17,6 +20,11 @@ B = bi.BASE
 
 def _ctx(v, m, **kw):
     return MA.barrett_precompute(jnp.asarray(bi.from_int(v, m)), **kw)
+
+
+def _one_slot(ctx):
+    """A one-slot context slab holding ctx."""
+    return MA.ContextSlab(*(c[None] for c in ctx))
 
 
 def _reduce(ctx, x, m, **kw):
@@ -96,18 +104,19 @@ def test_modmul_random(m):
 
 
 def test_modmul_batch_pallas_batched():
-    """impl dispatch to the natively batched kernel: the shared-context
-    vmap hands whole batches to one launch (custom_vmap rule)."""
+    """impl dispatch to the natively batched kernel: the per-row
+    context vmap hands whole batches to one launch (custom_vmap rule);
+    every row names the one slot."""
     rnd = random.Random(51)
     m = 4
     v = rnd.randint(B ** (m - 1), B ** m - 1)
-    ctx = _ctx(v, m, impl="pallas_batched")
+    slab = _one_slot(_ctx(v, m, impl="pallas_batched"))
     aa = [rnd.randint(0, B ** m - 1) for _ in range(4)]
     bb = [rnd.randint(0, B ** m - 1) for _ in range(4)]
-    out = MA.modmul_shared_batch(ctx,
-                                 jnp.asarray(bi.batch_from_ints(aa, m)),
-                                 jnp.asarray(bi.batch_from_ints(bb, m)),
-                                 impl="pallas_batched")
+    out = jax.jit(partial(MA.modmul_rows, impl="pallas_batched"))(
+        slab, jnp.zeros((4,), jnp.int32),
+        jnp.asarray(bi.batch_from_ints(aa, m)),
+        jnp.asarray(bi.batch_from_ints(bb, m)))
     for a, b, o in zip(aa, bb, bi.batch_to_ints(out)):
         assert o == (a * b) % v
 
@@ -187,11 +196,12 @@ def test_shared_context_batch():
     rnd = random.Random(11)
     m, em, n = 8, 2, 4
     v = rnd.randint(2, B ** m - 1)
-    ctx = _ctx(v, m)
+    slab = _one_slot(_ctx(v, m))
     az = [rnd.randint(0, B ** m - 1) for _ in range(n)]
     es = [rnd.randint(0, B ** em - 1) for _ in range(n)]
-    me = MA.modexp_shared_batch(ctx, jnp.asarray(bi.batch_from_ints(az, m)),
-                                jnp.asarray(bi.batch_from_ints(es, em)))
+    me = jax.jit(MA.modexp_rows)(slab, jnp.zeros((n,), jnp.int32),
+                                 jnp.asarray(bi.batch_from_ints(az, m)),
+                                 jnp.asarray(bi.batch_from_ints(es, em)))
     assert bi.batch_to_ints(np.asarray(me)) == [pow(a, e, v) for a, e
                                                 in zip(az, es)]
 
@@ -213,3 +223,34 @@ def test_reduce_property(x_lo, x_hi, v):
     m = 8
     x = x_hi * B ** 8 + x_lo                 # x < B^(2m), reduce's domain
     assert _reduce(_ctx(v, m), x, m) == x % v
+
+
+# ---------------------------------------------------------------------------
+# per-row contexts: the serving layer's slab
+# ---------------------------------------------------------------------------
+
+def test_batched_precompute_is_bit_equal_to_per_key():
+    """One batched precompute into slab slots (padding repeats a row)
+    gives each key the context a single-key precompute gives, and its
+    mu is the reference's shinv (core/pyref.py), floor(B^h / v) +
+    lambda with lambda in {0, 1} (Theorem 2)."""
+    rnd = random.Random(17)
+    m, slots = 4, 8
+    h = MA.barrett_h(m)
+    keys = [rnd.randint(2, B ** m - 1) for _ in range(4)] + [3, B ** m - 1]
+    at = [6, 0, 3, 7, 1, 4]
+    pad = 8
+    vs = jnp.asarray(bi.batch_from_ints(keys + [keys[0]] * (pad - 6), m))
+    idx = jnp.asarray(at + [at[0]] * (pad - 6), jnp.int32)
+    slab = jax.jit(MA.precompute_into)(MA.empty_slab(slots, m), vs, idx)
+    precompute = jax.jit(MA.barrett_precompute)
+    for key, s in zip(keys, at):
+        one = precompute(jnp.asarray(bi.from_int(key, m)))
+        assert bi.to_int(np.asarray(slab.v[s])) == key
+        assert np.array_equal(np.asarray(slab.mu[s]), np.asarray(one.mu))
+        assert int(slab.k[s]) == int(one.k)
+        mu = bi.to_int(np.asarray(slab.mu[s]))
+        assert mu == pyref.shinv(key, h, B)
+        assert mu - B ** h // key in (0, 1)
+    untouched = sorted(set(range(slots)) - set(at))
+    assert not np.asarray(slab.mu)[untouched].any()

@@ -123,6 +123,13 @@ def test_validation_rejects_types_and_ranges_with_index():
     # mismatched column lengths name both columns
     with pytest.raises(ValueError, match=r"len\(a\) = 2.*len\(b\) = 1"):
         svc.modmul([1, 2], [3], v)
+    # one modulus per row: the count must match, each is checked
+    with pytest.raises(ValueError, match=r"len\(x\) = 2.*len\(v\) = 1"):
+        svc.reduce([1, 2], [v])
+    with pytest.raises(ValueError, match=r"v\[1\] must be positive"):
+        svc.reduce([1, 2], [v, 0])
+    with pytest.raises(TypeError, match=r"v\[0\].*float"):
+        svc.reduce([1], [7.0])
     # everything above was rejected before compute
     assert svc._fns.misses <= 1             # only the valid modexp
     assert svc.ctx_misses <= 1
@@ -150,3 +157,126 @@ def test_service_same_ladder_different_exponents():
     a = [2, 3, 5, 7]
     e = [0, 1, 65535, 2 ** 31 - 1]
     assert svc.modexp(a, e, v) == [pow(x, y, v) for x, y in zip(a, e)]
+
+
+# ---------------------------------------------------------------------------
+# per-row moduli: many keys in one call, against Python's pow / %
+# ---------------------------------------------------------------------------
+
+def _keys(rnd, n, m):
+    return [rnd.randint(2, B ** m - 1) | 1 for _ in range(n)]
+
+
+def _rows(rnd, op, moduli, m, em=2):
+    """Request columns of `op` for rows with these moduli, and the
+    reference answers (Python's pow and %)."""
+    if op == "reduce":
+        xs = [rnd.randint(0, B ** (2 * m) - 1) for _ in moduli]
+        return [xs], [x % v for x, v in zip(xs, moduli)]
+    a = [rnd.randint(0, B ** m - 1) for _ in moduli]
+    if op == "modmul":
+        b = [rnd.randint(0, B ** m - 1) for _ in moduli]
+        return [a, b], [(x * y) % v for x, y, v in zip(a, b, moduli)]
+    e = [rnd.randint(0, B ** em - 1) for _ in moduli]
+    return [a, e], [pow(x, y, v) for x, y, v in zip(a, e, moduli)]
+
+
+@pytest.mark.parametrize("op", ["reduce", "modmul", "modexp"])
+def test_per_row_moduli_over_many_keys_match_the_reference(op):
+    """11 rows over 5 seeded keys in one call: two bucket chunks, each
+    row reduced by its own modulus, every key precomputed once."""
+    rnd = random.Random(40)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=2, impl="blocked",
+                          batch_buckets=(8,), capture_profiles=False)
+    keys = _keys(rnd, 5, m)
+    moduli = [rnd.choice(keys) for _ in range(11)]
+    cols, want = _rows(rnd, op, moduli, m)
+    assert getattr(svc, op)(*cols, moduli) == want
+    st = svc.stats()
+    assert st["bucket_seconds"][f"{op}/b8"]["count"] == 2
+    assert svc.ctx_misses == len(set(moduli))
+    calls, = svc.telemetry.registry.get("call_moduli").series()
+    assert calls.value == len(set(moduli[:8])) + len(set(moduli[8:]))
+
+
+def test_one_key_is_the_single_modulus_case():
+    """One modulus for every row, given once or once per row, is the
+    same call: the same answers, one context, one slot."""
+    rnd = random.Random(41)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=2, impl="blocked",
+                          batch_buckets=(4,), capture_profiles=False)
+    v = _keys(rnd, 1, m)[0]
+    (a, e), want = _rows(rnd, "modexp", [v] * 6, m)
+    assert svc.modexp(a, e, v) == want
+    assert svc.modexp(a, e, [v] * 6) == want
+    assert (svc.ctx_misses, svc.ctx_hits) == (1, 3)   # chunks (4, 2) x 2
+    assert svc.stats()["ctx_cache"]["size"] == 1
+
+
+@pytest.mark.parametrize("slots,calls", [(1, 6), (3, 2), (6, 1)])
+def test_more_distinct_moduli_than_slots_still_answer_exactly(slots, calls):
+    """8 rows over 6 keys in one bucket chunk: with fewer slots than
+    keys the chunk is split into parts of at most `slots` keys, each
+    its own bucket call, and the slab never holds more than it has."""
+    rnd = random.Random(42)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=1, impl="blocked",
+                          batch_buckets=(2, 8), max_cached_moduli=slots,
+                          capture_profiles=False)
+    keys = _keys(rnd, 6, m)
+    moduli = keys + keys[:2]
+    rnd.shuffle(moduli)
+    (xs,), want = _rows(rnd, "reduce", moduli, m)
+    assert svc.reduce(xs, moduli) == want
+    st = svc.stats()
+    assert sum(h["count"] for h in st["bucket_seconds"].values()) == calls
+    assert st["ctx_cache"]["size"] == slots
+    assert svc.ctx_misses == 6 and svc.ctx_evictions == 6 - slots
+
+
+def test_eviction_between_calls_keeps_answers_exact():
+    """A 2-slot slab over 3 keys: each call's cold key takes the least
+    recently used slot of a key the call does not name."""
+    rnd = random.Random(43)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=1, impl="blocked",
+                          batch_buckets=(4,), max_cached_moduli=2,
+                          capture_profiles=False)
+    k0, k1, k2 = _keys(rnd, 3, m)
+    for moduli, hits, misses in [([k0, k1, k0], 0, 2),
+                                 ([k1, k2], 1, 1),      # evicts k0
+                                 ([k0, k2, k2], 1, 1),  # evicts k1
+                                 ([k1], 0, 1)]:         # evicts k2
+        h0, m0 = svc.ctx_hits, svc.ctx_misses
+        (a, b), want = _rows(rnd, "modmul", moduli, m)
+        assert svc.modmul(a, b, moduli) == want
+        assert (svc.ctx_hits - h0, svc.ctx_misses - m0) == (hits, misses)
+    assert svc.ctx_evictions == 3
+    assert list(svc._ctxs) == [k0, k1]
+
+
+def test_a_failed_precompute_leaves_no_context_behind():
+    """A batched precompute that raises frees the slots its cold moduli
+    took, so the next call computes them again and answers exactly."""
+    rnd = random.Random(44)
+    m = 4
+    svc = ModArithService(m_limbs=m, e_limbs=1, impl="blocked",
+                          batch_buckets=(4,), max_cached_moduli=2,
+                          capture_profiles=False)
+    k0, k1, k2 = _keys(rnd, 3, m)
+    (a, b), want = _rows(rnd, "modmul", [k0, k1], m)
+    assert svc.modmul(a, b, [k0, k1]) == want
+    fill = svc._fill
+
+    def broken(cold):
+        raise RuntimeError("device lost")
+    svc._fill = broken
+    with pytest.raises(RuntimeError, match="device lost"):
+        svc.modmul(a, b, [k0, k2])               # k2 cold, evicts k1
+    assert list(svc._ctxs) == [k0] and svc._free == [1]
+    svc._fill = fill
+    (a, b), want = _rows(rnd, "modmul", [k2, k1, k0], m)
+    assert svc.modmul(a[:2], b[:2], [k2, k1]) == want[:2]
+    assert svc.modmul(a[2:], b[2:], [k0]) == want[2:]

@@ -249,11 +249,19 @@ def test_modexp_service_records_each_phase_once_per_chunk():
     assert svc.modexp(a[:1], e[:1], v) == [pow(a[0], e[0], v)]
     counts = _phase_counts(svc)
     chunks = 2 + 1                            # (0, 2), (2, 3); then (0, 1)
-    assert counts == {"modexp/validate": 2, "barrett/precompute": 1,
+    # each chunk looks up its contexts; the one cold key is computed
+    # inside the first chunk's lookup
+    assert counts == {"modexp/validate": 2, "modexp/contexts": chunks,
+                      "modexp/precompute": 1,
                       "modexp/pack": chunks, "modexp/execute": chunks,
                       "modexp/unpack": chunks}
     st = svc.stats()
     assert st["bucket_seconds"]["modexp/b2"]["count"] == chunks
+    reg = svc.telemetry.registry
+    calls, = reg.get("call_moduli").series()
+    assert (calls.count, calls.value) == (chunks, chunks)
+    fills, = reg.get("ctx_precompute_rows").series()
+    assert (fills.count, fills.value) == (1, 1)
 
 
 def test_modarith_ctx_cache_counters_exact():
@@ -272,7 +280,7 @@ def test_modarith_ctx_cache_counters_exact():
     svc.reduce(x, vs[2])
     st = svc.stats()["ctx_cache"]
     assert st == {"hits": 2, "misses": 3, "evictions": 1, "size": 2,
-                  "hit_rate": pytest.approx(2 / 5)}
+                  "slots": 2, "hit_rate": pytest.approx(2 / 5)}
     # the labeled counter series carries the same events
     ctx = svc.telemetry.registry.get("ctx_cache_total")
     by_event = {s.labels["event"]: s.value for s in ctx.series()}

@@ -10,6 +10,7 @@ only real sleeps are the (millisecond-scaled) retry backoffs.
 
 import asyncio
 import random
+import sys
 import threading
 from concurrent.futures import ThreadPoolExecutor
 
@@ -248,6 +249,36 @@ def test_concurrent_context_lru_stays_consistent():
     assert svc.ctx_evictions == svc.ctx_misses - 3
 
 
+def test_concurrent_calls_over_more_keys_than_slots_stay_exact():
+    """More threads than cores share one 3-slot context slab over 9
+    keys, each call naming up to 4 of them (so some calls split): one
+    call's misses never evict the slot of a context another call is
+    using, and every answer stays exact."""
+    rnd = random.Random(13)
+    m = 2
+    svc = ModArithService(m_limbs=m, e_limbs=1, impl="blocked",
+                          batch_buckets=(4,), max_cached_moduli=3,
+                          capture_profiles=False)
+    keys = [rnd.randint(2, B ** m - 1) for _ in range(9)]
+    jobs = [([rnd.randint(0, B ** m - 1) for _ in range(4)],
+             [rnd.randint(0, B ** m - 1) for _ in range(4)],
+             [rnd.choice(keys) for _ in range(4)]) for _ in range(48)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(max_workers=12) as pool:
+            futs = [pool.submit(svc.modmul, a, b, vs) for a, b, vs in jobs]
+            results = [f.result(timeout=300) for f in futs]
+    finally:
+        sys.setswitchinterval(switch)
+    for (a, b, vs), res in zip(jobs, results):
+        assert res == [(x * y) % v for x, y, v in zip(a, b, vs)]
+    assert len(svc._ctxs) == 3
+    assert svc.ctx_hits + svc.ctx_misses == \
+        sum(len(set(vs)) for _, _, vs in jobs)
+    assert svc.ctx_evictions == svc.ctx_misses - 3
+
+
 # ---------------------------------------------------------------------------
 # async frontend: retry, deadlines, degradation, overload
 # ---------------------------------------------------------------------------
@@ -441,10 +472,14 @@ def test_frontend_queued_work_estimate_limit():
     run(main())
 
 
-def test_frontend_coalesces_concurrent_requests_into_one_bucket():
+@pytest.mark.parametrize("keys", [1, 4])
+def test_frontend_coalesces_concurrent_requests_into_one_bucket(keys):
+    """Requests over one modulus or over several share bucket chunks:
+    each row carries its own modulus into the call."""
     rnd = random.Random(28)
     svc = _modarith()
-    v = rnd.randint(2, B ** 3 - 1)
+    vs = [rnd.randint(2, B ** 3 - 1) for _ in range(keys)]
+    v = [vs[i % keys] for i in range(4)]
     a = [rnd.randint(0, B ** 3 - 1) for _ in range(4)]
     b = [rnd.randint(0, B ** 3 - 1) for _ in range(4)]
 
@@ -452,15 +487,125 @@ def test_frontend_coalesces_concurrent_requests_into_one_bucket():
         async with AsyncFrontend(svc,
                                  policy=ServingPolicy(**FAST)) as fe:
             outs = await asyncio.gather(*[
-                fe.submit("modmul", [a[i]], [b[i]], v=v)
+                fe.submit("modmul", [a[i]], [b[i]], v=v[i])
                 for i in range(4)])
             assert [o[0] for o in outs] == \
-                [(x * y) % v for x, y in zip(a, b)]
+                [(x * y) % w for x, y, w in zip(a, b, v)]
     run(main())
     st = svc.telemetry.stats()
     # 4 single-row requests coalesced into at most 2 padded buckets
-    # (first arrival may start a cycle alone) -- NOT 4 buckets
+    # (first arrival may start a cycle alone) -- NOT one per request
+    # or per modulus
     assert st["rows_padded"] <= 8, st
+    assert svc.ctx_misses == keys
+
+
+def test_frontend_hands_a_service_without_a_slab_one_modulus_a_call():
+    """A stand-in whose `stats()` reports no context slab (as one that
+    serves a reference one modulus at a time) gets each chunk's
+    modulus as one int: its requests group by (op, modulus)."""
+    rnd = random.Random(38)
+    real = _modarith()
+
+    class OneModulus:
+        def __init__(self):
+            self.seen = []
+
+        def __getattr__(self, name):
+            return getattr(real, name)
+
+        def stats(self):
+            return {"ctx_cache": {"size": 0, "hits": 0, "misses": 0}}
+
+        def modmul(self, a, b, v, *, impl=None):
+            self.seen.append(v)
+            return real.modmul(a, b, v, impl=impl)
+
+    svc = OneModulus()
+    vs = [rnd.randint(2, B ** 3 - 1) for _ in range(2)]
+    a = [rnd.randint(0, B ** 3 - 1) for _ in range(4)]
+
+    async def main():
+        async with AsyncFrontend(svc, policy=ServingPolicy(**FAST)) as fe:
+            return await asyncio.gather(*[
+                fe.submit("modmul", [a[i]], [a[i]], v=vs[i % 2])
+                for i in range(4)])
+    outs = run(main())
+    assert [o[0] for o in outs] == [x * x % vs[i % 2]
+                                    for i, x in enumerate(a)]
+    assert all(isinstance(v, int) for v in svc.seen)
+    assert len(svc.seen) >= 2
+
+
+class _Probe(FaultInjector):
+    """Fault injector that records every site it is asked about (and
+    can advance a fake clock at each execute)."""
+
+    def __init__(self, specs=(), clock=None):
+        super().__init__(list(specs))
+        self.clock = clock
+        self.sites = []
+
+    def fire(self, site, **labels):
+        self.sites.append(site)
+        if site == "execute" and self.clock is not None:
+            self.clock[0] += 1.0
+        super().fire(site, **labels)
+
+
+@pytest.mark.parametrize("case", ["split", "retry", "degrade", "deadline"])
+def test_frontend_coalesces_across_moduli_keeping_rows_aligned(case):
+    """Six requests over four moduli coalesce into one group whose 10
+    rows `Batcher.plan` splits into bucket-4 chunks (4, 4, 2), each
+    carrying its rows' moduli.  A transient retry, a kernel fault that
+    degrades the chunk down the ladder, and a deadline that expires one
+    request between chunks each leave every other row answered by its
+    own modulus."""
+    rnd = random.Random(37)
+    m = 3
+    impl = "pallas_batched" if case == "degrade" else "blocked"
+    svc = _modarith(m=m, impl=impl)
+    keys = [rnd.randint(2, B ** m - 1) for _ in range(4)]
+    sizes = [3, 2, 1, 2, 1, 1]
+    mods = [keys[0], keys[1], keys[0], keys[2], keys[3], keys[1]]
+    reqs = [([rnd.randint(0, B ** m - 1) for _ in range(n)],
+             [rnd.randint(0, B ** m - 1) for _ in range(n)], v)
+            for n, v in zip(sizes, mods)]
+    clock = [0.0]
+    specs = {"retry": [FaultSpec(site="execute", op="modmul", times=2)],
+             "degrade": [FaultSpec(site="execute", impl="pallas_batched",
+                                   kind="kernel", times=0)]}
+    inj = _Probe(specs.get(case, ()),
+                 clock if case == "deadline" else None)
+    # request 1 (rows 3-4) spans chunks 1 and 2
+    timeouts = [None, 0.5 if case == "deadline" else None] + [None] * 4
+
+    async def main():
+        async with AsyncFrontend(svc, policy=ServingPolicy(**FAST),
+                                 faults=inj, clock=lambda: clock[0]) as fe:
+            outs = await asyncio.gather(*[
+                fe.submit("modmul", a, b, v=v, timeout=t)
+                for (a, b, v), t in zip(reqs, timeouts)],
+                return_exceptions=True)
+            return outs, fe.healthz(), fe.metrics
+    outs, health, metrics = run(main())
+    for k, ((a, b, v), out) in enumerate(zip(reqs, outs)):
+        if case == "deadline" and k == 1:      # expired after chunk 1
+            assert isinstance(out, E.DeadlineExceeded)
+            assert (out.completed, out.total) == (1, 2)
+            continue
+        assert out == [(x * y) % v for x, y in zip(a, b)], (case, k)
+    assert health["dropped"] == 0
+    # three chunk executions, and one more per failed attempt (the
+    # breaker quarantines pallas_batched at its first kernel fault)
+    extra = {"retry": 2, "degrade": 1}.get(case, 0)
+    assert inj.sites.count("execute") == 3 + extra
+    assert svc.telemetry.stats()["rows_true"] == 10 + 4 * extra
+    if case == "degrade":
+        assert svc.kernel_plans[4].impl == "blocked"
+        assert health["quarantine"] == ["pallas_batched/b4/m3"]
+    assert sum(s.value for s in metrics.retries.series()) == \
+        (2 if case == "retry" else 0)
 
 
 @pytest.mark.parametrize("op", ["divmod", "modexp"])

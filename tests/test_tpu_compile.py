@@ -84,12 +84,39 @@ def test_fused_divmod(one_chip, bits, path):
     assert len(names) == 2 * S.refine_iters(m) + 1
 
 
-def test_barrett_reduce_2048(one_chip):
+def _slab(one_chip, slots, m):
+    return MA.ContextSlab(
+        v=_limbs(one_chip, slots, m),
+        mu=_limbs(one_chip, slots, MA.barrett_width(m)),
+        k=jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip))
+
+
+def _slots(one_chip, n):
+    return jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+
+
+@pytest.mark.parametrize("contexts", ["shared", "per_row"])
+def test_barrett_reduce_2048(one_chip, contexts):
     m = 128
-    ctx = MA.BarrettContext(
-        v=_limbs(one_chip, m), mu=_limbs(one_chip, MA.barrett_width(m)),
-        k=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
-    names = _assert_kernel(
-        lambda c, x: MA.reduce_shared(c, x, impl="pallas_fused"),
-        ctx, _limbs(one_chip, 16, 2 * m))
+    x = _limbs(one_chip, 16, 2 * m)
+    if contexts == "shared":
+        ctx = MA.BarrettContext(
+            v=_limbs(one_chip, m), mu=_limbs(one_chip, MA.barrett_width(m)),
+            k=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+        names = _assert_kernel(
+            lambda c, x: MA.reduce_shared(c, x, impl="pallas_fused"), ctx, x)
+    else:                     # the served path: contexts gathered by slot
+        names = _assert_kernel(
+            lambda sl, i, x: MA.reduce_rows(sl, i, x, impl="pallas_fused"),
+            _slab(one_chip, 64, m), _slots(one_chip, 16), x)
     assert names == {"barrett"}
+
+
+def test_batched_context_precompute_2048(one_chip):
+    """The served path's precompute: 64 cold 2048-bit moduli in one
+    batched shinv, written into a 64-slot slab."""
+    m = 128
+    names = _assert_kernel(
+        lambda sl, v, i: MA.precompute_into(sl, v, i, impl="pallas_fused"),
+        _slab(one_chip, 64, m), _limbs(one_chip, 64, m), _slots(one_chip, 64))
+    assert names and all(n.startswith("refine_i") for n in names)
